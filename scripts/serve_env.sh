@@ -21,27 +21,10 @@ export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD="${TCMALLOC_LARGE_ALLOC_REPORT_THRE
 # quiet TF/XLA init chatter; serving logs should be the engine's own
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 
-# float32 by default: the reduced-config CPU path assumes it, and silent
-# x64 promotion doubles every cache slot
-export JAX_DEFAULT_DTYPE_BITS="${JAX_DEFAULT_DTYPE_BITS:-32}"
-
 # SERVE_DEVICES=N simulates an N-device host platform (useful for sharded
 # serving experiments on one machine)
-XLA_EXTRA=""
 if [[ -n "${SERVE_DEVICES:-}" ]]; then
-  XLA_EXTRA="--xla_force_host_platform_device_count=${SERVE_DEVICES}"
-fi
-
-# decode-relevant GPU flags (harmless on CPU: only applied when a GPU is
-# visible): latency-hiding scheduling and command buffers keep the
-# one-token-per-step launch overhead off the critical path
-if command -v nvidia-smi >/dev/null 2>&1 && nvidia-smi >/dev/null 2>&1; then
-  XLA_EXTRA="$XLA_EXTRA --xla_gpu_enable_latency_hiding_scheduler=true \
---xla_gpu_enable_command_buffer=FUSION,CUBLAS,CUDNN \
---xla_gpu_all_reduce_combine_threshold_bytes=134217728"
-fi
-if [[ -n "$XLA_EXTRA" ]]; then
-  export XLA_FLAGS="${XLA_FLAGS:-}${XLA_FLAGS:+ }${XLA_EXTRA}"
+  export XLA_FLAGS="${XLA_FLAGS:-}${XLA_FLAGS:+ }--xla_force_host_platform_device_count=${SERVE_DEVICES}"
 fi
 
 exec "$@"

@@ -1,9 +1,7 @@
 #!/usr/bin/env bash
 # Training runtime hygiene: exec a command under the allocator and XLA
 # settings that matter for federated training drivers — especially the
-# cohort-scan engine, whose shard loop churns large stacked host buffers
-# and (on a real mesh) leans on pipelined collectives for the per-shard
-# aggregation all-reduce.
+# cohort-scan engine, whose shard loop churns large stacked host buffers.
 #
 #   scripts/train_env.sh python -m repro.launch.train --clients 100000 ...
 #   TRAIN_DEVICES=8 scripts/train_env.sh python benchmarks/round_throughput.py
@@ -25,36 +23,10 @@ export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD="${TCMALLOC_LARGE_ALLOC_REPORT_THRE
 # quiet TF/XLA init chatter; training logs should be the round ledger
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 
-# float32 by default: the reduced-config CPU path assumes it, and silent
-# x64 promotion doubles every stacked client buffer
-export JAX_DEFAULT_DTYPE_BITS="${JAX_DEFAULT_DTYPE_BITS:-32}"
-
 # TRAIN_DEVICES=N simulates an N-device host platform (client-axis sharding
 # experiments — COHORT_RULES / the 512-device fixtures — on one machine)
-XLA_EXTRA=""
 if [[ -n "${TRAIN_DEVICES:-}" ]]; then
-  XLA_EXTRA="--xla_force_host_platform_device_count=${TRAIN_DEVICES}"
-fi
-
-# MaxText-style GPU collective flags (harmless on CPU: only applied when a
-# GPU is visible): the latency-hiding scheduler overlaps the per-shard
-# aggregation all-reduce with the next shard's compute, pipelined
-# collectives + fat combine thresholds keep the model-sized payloads off
-# the critical path, and double-buffered while loops serve the scanned
-# local epochs.
-if command -v nvidia-smi >/dev/null 2>&1 && nvidia-smi >/dev/null 2>&1; then
-  XLA_EXTRA="$XLA_EXTRA --xla_gpu_enable_latency_hiding_scheduler=true \
---xla_gpu_enable_highest_priority_async_stream=true \
---xla_gpu_all_reduce_combine_threshold_bytes=134217728 \
---xla_gpu_all_gather_combine_threshold_bytes=1073741824 \
---xla_gpu_reduce_scatter_combine_threshold_bytes=33554432 \
---xla_gpu_enable_pipelined_all_reduce=true \
---xla_gpu_enable_pipelined_all_gather=true \
---xla_gpu_enable_pipelined_reduce_scatter=true \
---xla_gpu_enable_while_loop_double_buffering=true"
-fi
-if [[ -n "$XLA_EXTRA" ]]; then
-  export XLA_FLAGS="${XLA_FLAGS:-}${XLA_FLAGS:+ }${XLA_EXTRA}"
+  export XLA_FLAGS="${XLA_FLAGS:-}${XLA_FLAGS:+ }--xla_force_host_platform_device_count=${TRAIN_DEVICES}"
 fi
 
 exec "$@"

@@ -37,11 +37,7 @@ from repro import obs
 from repro.conformance import cases as _cases
 from repro.conformance import tolerances as _tol
 from repro.conformance.cases import CASES, KERNELS, Case
-
-
-def interpret_mode() -> bool:
-    """True when Pallas kernels run interpreted (any non-TPU backend)."""
-    return jax.default_backend() != "tpu"
+from repro.kernels.ops import interpret_mode
 
 
 @dataclasses.dataclass(frozen=True)

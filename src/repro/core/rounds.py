@@ -816,6 +816,10 @@ class FedSession:
                     scalar_fold(tok_acc, toks))
 
         fed_shard = jax.jit(_fed_shard)
+        # the per-shard program and the abstract arguments of its first
+        # call: ``shard_program.lower(*shard_args).compile()`` is the
+        # program the rounds run, for checks of its text and memory
+        self.shard_program, self.shard_args = fed_shard, None
 
         @jax.jit
         def norm_weights(w):
@@ -881,10 +885,14 @@ class FedSession:
                         else:
                             fmasks = jnp.zeros((len(ids), n_units),
                                                jnp.float32)
-                        partial, loss_acc, tok_acc = fed_shard(
-                            params, base, partial, loss_acc, tok_acc, bsub,
-                            fmasks, w_agg[off:off + width],
-                            w_loss[off:off + width])
+                        args = (params, base, partial, loss_acc, tok_acc,
+                                bsub, fmasks, w_agg[off:off + width],
+                                w_loss[off:off + width])
+                        if self.shard_args is None:
+                            self.shard_args = jax.tree.map(
+                                lambda x: jax.ShapeDtypeStruct(x.shape,
+                                                               x.dtype), args)
+                        partial, loss_acc, tok_acc = fed_shard(*args)
                     off += width
                 with _obs_span("train.aggregate", cat="train", round=t,
                                clients=m):

@@ -83,7 +83,7 @@ def _pad_to(x, mult, axis):
     static_argnames=("causal", "window", "softcap", "block_q", "block_k",
                      "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    block_q=128, block_k=128, interpret=True):
+                    block_q=128, block_k=128, interpret):
     """q: (B,S,H,D); k,v: (B,T,Kv,D) -> (B,S,H,D)."""
     B, S, H, D = q.shape
     T, Kv = k.shape[1], k.shape[2]

@@ -18,29 +18,30 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref, y_ref, hT_ref, h_scr,
-            *, chunk, H, Pd, N, nt):
+            dt_scr, b_scr, c_scr, *, nt):
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
         h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)          # (chunk, H, P)
-    dt = dt_ref[0].astype(jnp.float32)        # (chunk, H)
-    a = -jnp.exp(a_ref[...].astype(jnp.float32))   # (H,)
-    b = b_ref[0].astype(jnp.float32)          # (chunk, N)
-    c = c_ref[0].astype(jnp.float32)          # (chunk, N)
+    # fp32 copies of the (chunk, H|N) tiles: the time loop reads one row per
+    # step, and Mosaic slices rows at a traced offset only from 32-bit tiles
+    for src, dst in ((dt_ref, dt_scr), (b_ref, b_scr), (c_ref, c_scr)):
+        dst[...] = src[0].astype(jnp.float32)
+    a = -jnp.exp(a_ref[...].astype(jnp.float32))    # (1, H)
 
     def body(i, h):
-        decay = jnp.exp(dt[i] * a)                          # (H,)
-        dbx = (dt[i][:, None] * x[i])[..., None] * b[i][None, None, :]
-        h = decay[:, None, None] * h + dbx                  # (H,P,N)
-        y = jax.lax.dot_general(h.reshape(H * Pd, N), c[i][:, None],
-                                (((1,), (0,)), ((), ())))   # (H*P, 1)
-        y_ref[0, i] = y.reshape(H, Pd).astype(y_ref.dtype)
+        row = pl.ds(i, 1)
+        dt = dt_scr[row].T                                  # (H, 1)
+        decay = jnp.exp(dt * a.T)[:, :, None]               # (H, 1, 1)
+        dx = dt * x_ref[0, i].astype(jnp.float32)           # (H, P)
+        h = decay * h + dx[:, :, None] * b_scr[row][None]   # (H, P, N)
+        y = jnp.sum(h * c_scr[row][None], axis=-1)          # (H, P)
+        y_ref[0, i] = y.astype(y_ref.dtype)
         return h
 
-    h_scr[...] = jax.lax.fori_loop(0, chunk, body, h_scr[...])
+    h_scr[...] = jax.lax.fori_loop(0, dt_scr.shape[0], body, h_scr[...])
 
     @pl.when(t == nt - 1)
     def _fin():
@@ -48,7 +49,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref, y_ref, hT_ref, h_scr,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def mamba2_scan(x, dt, a_log, b, c, h0, *, chunk=128, interpret=True):
+def mamba2_scan(x, dt, a_log, b, c, h0, *, chunk=128, interpret):
     """See ref.mamba2_scan: x (B,T,H,P), dt (B,T,H), a_log (H,), b/c (B,T,N),
     h0 (B,H,P,N) -> (y (B,T,H,P), hT)."""
     B, T, H, Pd = x.shape
@@ -64,12 +65,12 @@ def mamba2_scan(x, dt, a_log, b, c, h0, *, chunk=128, interpret=True):
     nt = Tp // chunk
 
     y, hT = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, H=H, Pd=Pd, N=N, nt=nt),
+        functools.partial(_kernel, nt=nt),
         grid=(B, nt),
         in_specs=[
             pl.BlockSpec((1, chunk, H, Pd), lambda i, t: (i, t, 0, 0)),
             pl.BlockSpec((1, chunk, H), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((H,), lambda i, t: (0,)),
+            pl.BlockSpec((1, H), lambda i, t: (0, 0)),
             pl.BlockSpec((1, chunk, N), lambda i, t: (i, t, 0)),
             pl.BlockSpec((1, chunk, N), lambda i, t: (i, t, 0)),
             pl.BlockSpec((1, H, Pd, N), lambda i, t: (i, 0, 0, 0)),
@@ -82,8 +83,11 @@ def mamba2_scan(x, dt, a_log, b, c, h0, *, chunk=128, interpret=True):
             jax.ShapeDtypeStruct((B, Tp, H, Pd), x.dtype),
             jax.ShapeDtypeStruct((B, H, Pd, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((H, Pd, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((H, Pd, N), jnp.float32),
+                        pltpu.VMEM((chunk, H), jnp.float32),
+                        pltpu.VMEM((chunk, N), jnp.float32),
+                        pltpu.VMEM((chunk, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a_log, b, c, h0)
+    )(x, dt, a_log.reshape(1, H), b, c, h0)
 
     return y[:, :T], hT

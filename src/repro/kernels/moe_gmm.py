@@ -43,7 +43,7 @@ def _kernel(x_ref, wg_ref, wu_ref, wo_ref, y_ref, acc_ref, *, nf):
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f", "interpret"))
 def moe_ffn(xe, wi_gate, wi_up, wo, *, block_c=128, block_f=128,
-            interpret=True):
+            interpret):
     """xe: (E,C,d); wi_gate/wi_up: (E,d,f); wo: (E,f,d) -> (E,C,d)."""
     E, C, d = xe.shape
     f = wi_gate.shape[-1]

@@ -1,9 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the kernel
-body executes step-by-step in Python, exercising exactly the same BlockSpec
-tiling/indexing that would run on TPU.  On a TPU backend the same call sites
-compile to Mosaic.  ``impl="xla"`` callers bypass kernels entirely and use
+This module is the one place that decides whether a kernel is interpreted
+(``interpret_mode``): on a TPU backend every call site compiles to Mosaic;
+on any other backend (the CPU test runs) the kernel body executes
+step-by-step in Python, exercising the same BlockSpec tiling/indexing.  The
+raw kernels take ``interpret`` as a required argument, so no caller falls
+back to the interpreter without naming it.  A test that compiles for a
+described TPU from a CPU process steers the choice by patching
+``interpret_mode``.  ``impl="xla"`` callers bypass kernels entirely and use
 :mod:`repro.kernels.ref` (that is what the dry-run lowers, keeping the
 roofline numbers kernel-agnostic).
 
@@ -28,7 +32,8 @@ from repro.kernels.mamba2_scan import mamba2_scan as _mamba2
 from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv6
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """True when Pallas kernels run interpreted (any non-TPU backend)."""
     return jax.default_backend() != "tpu"
 
 
@@ -38,7 +43,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     def fa(q, k, v):
         return _flash(q, k, v, causal=causal, window=window, softcap=softcap,
                       block_q=block_q, block_k=block_k,
-                      interpret=_interpret())
+                      interpret=interpret_mode())
 
     def fwd(q, k, v):
         return fa(q, k, v), (q, k, v)
@@ -54,7 +59,8 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 def rwkv6_scan(r, k, v, w, u, s0, *, chunk=128):
     @jax.custom_vjp
     def wkv(r, k, v, w, u, s0):
-        return _rwkv6(r, k, v, w, u, s0, chunk=chunk, interpret=_interpret())
+        return _rwkv6(r, k, v, w, u, s0, chunk=chunk,
+                      interpret=interpret_mode())
 
     def fwd(r, k, v, w, u, s0):
         return wkv(r, k, v, w, u, s0), (r, k, v, w, u, s0)
@@ -70,7 +76,7 @@ def mamba2_scan(x, dt, a_log, b, c, h0, *, chunk=128):
     @jax.custom_vjp
     def ssd(x, dt, a_log, b, c, h0):
         return _mamba2(x, dt, a_log, b, c, h0, chunk=chunk,
-                       interpret=_interpret())
+                       interpret=interpret_mode())
 
     def fwd(x, dt, a_log, b, c, h0):
         return ssd(x, dt, a_log, b, c, h0), (x, dt, a_log, b, c, h0)
@@ -86,7 +92,7 @@ def moe_ffn(xe, wi_gate, wi_up, wo, *, block_c=128, block_f=128):
     @jax.custom_vjp
     def gmm(xe, wi_gate, wi_up, wo):
         return _moe_ffn(xe, wi_gate, wi_up, wo, block_c=block_c,
-                        block_f=block_f, interpret=_interpret())
+                        block_f=block_f, interpret=interpret_mode())
 
     def fwd(xe, wi_gate, wi_up, wo):
         return gmm(xe, wi_gate, wi_up, wo), (xe, wi_gate, wi_up, wo)

@@ -25,27 +25,29 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_scr,
-            *, chunk, D, nt):
+            r_scr, k_scr, v_scr, w_scr, y_scr, *, nt):
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
         s_scr[...] = s0_ref[0].astype(jnp.float32)
 
-    r = r_ref[0].astype(jnp.float32)          # (chunk, D)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)          # (D,)
-    ruk = jnp.sum(r * u[None, :] * k, axis=-1)  # (chunk,)
+    # fp32 copies of the chunk: the time loop reads and writes one row per
+    # step, and Mosaic slices rows at a traced offset only from 32-bit tiles
+    for src, dst in ((r_ref, r_scr), (k_ref, k_scr), (v_ref, v_scr),
+                     (w_ref, w_scr)):
+        dst[...] = src[0].astype(jnp.float32)       # (chunk, D)
+    u = u_ref[0].astype(jnp.float32)                # (1, D)
 
     def body(i, s):
-        rt, kt, vt, wt = r[i], k[i], v[i], w[i]
-        y = rt @ s + ruk[i] * vt                              # (D,)
-        y_ref[0, i] = y.astype(y_ref.dtype)
-        return wt[:, None] * s + kt[:, None] * vt[None, :]
+        row = pl.ds(i, 1)
+        rt, kt, vt, wt = r_scr[row], k_scr[row], v_scr[row], w_scr[row]
+        ruk = jnp.sum(rt * u * kt, axis=-1, keepdims=True)   # (1, 1)
+        y_scr[row] = jnp.dot(rt, s) + ruk * vt
+        return wt.T * s + kt.T * vt
 
-    s_scr[...] = jax.lax.fori_loop(0, chunk, body, s_scr[...])
+    s_scr[...] = jax.lax.fori_loop(0, r_scr.shape[0], body, s_scr[...])
+    y_ref[0] = y_scr[...].astype(y_ref.dtype)
 
     @pl.when(t == nt - 1)
     def _fin():
@@ -53,7 +55,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_scr,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_scan(r, k, v, w, u, s0, *, chunk=128, interpret=True):
+def rwkv6_scan(r, k, v, w, u, s0, *, chunk=128, interpret):
     """r,k,v,w: (B,T,H,D); u: (H,D); s0: (B,H,D,D) -> (y, sT). See ref.rwkv6_scan."""
     B, T, H, D = r.shape
     chunk = min(chunk, T)
@@ -70,19 +72,19 @@ def rwkv6_scan(r, k, v, w, u, s0, *, chunk=128, interpret=True):
     if pad:
         tmask = (jnp.arange(T + pad) < T)[None, :, None]
         wf = jnp.where(tmask, wf, 1.0)
-    uf = jnp.broadcast_to(u[None], (B, H, D)).reshape(BH, D)
+    uf = jnp.broadcast_to(u[None], (B, H, D)).reshape(BH, 1, D)
     s0f = s0.reshape(BH, D, D)
     nt = (T + pad) // chunk
 
     y, sT = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, D=D, nt=nt),
+        functools.partial(_kernel, nt=nt),
         grid=(BH, nt),
         in_specs=[
             pl.BlockSpec((1, chunk, D), lambda i, t: (i, t, 0)),
             pl.BlockSpec((1, chunk, D), lambda i, t: (i, t, 0)),
             pl.BlockSpec((1, chunk, D), lambda i, t: (i, t, 0)),
             pl.BlockSpec((1, chunk, D), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, D), lambda i, t: (i, 0)),
+            pl.BlockSpec((1, 1, D), lambda i, t: (i, 0, 0)),
             pl.BlockSpec((1, D, D), lambda i, t: (i, 0, 0)),
         ],
         out_specs=[
@@ -93,7 +95,8 @@ def rwkv6_scan(r, k, v, w, u, s0, *, chunk=128, interpret=True):
             jax.ShapeDtypeStruct((BH, T + pad, D), r.dtype),
             jax.ShapeDtypeStruct((BH, D, D), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)]
+        + [pltpu.VMEM((chunk, D), jnp.float32)] * 5,
         interpret=interpret,
     )(rf, kf, vf, wf, uf, s0f)
 

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import use_compile_cache
 from repro.models.model import init_model
 from repro.nn import param as P
 from repro.serve import (DecodeEngine, EngineConfig, PoissonArrivals,
@@ -80,6 +81,7 @@ def main() -> None:
                          "measured/predicted falls outside [1/W, W]")
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     from repro import obs
     if args.trace_out:

@@ -1,9 +1,9 @@
 """Federated DAPT training driver (the paper's Stage-2 pipeline, end to end).
 
 Runs FDAPT / FFDAPT on the synthetic biomedical corpus with any arch from the
-zoo.  On this CPU container it defaults to the reduced config (the full
-configs are exercised by the dry-run); on a real TPU fleet the same driver
-runs the full config with the production mesh.
+zoo.  It defaults to the reduced config, which the CPU test runs use;
+``--full-config`` runs the published widths, as on one TPU chip
+(``chip_smoke.py`` drives this module's ``build`` at DistilBERT's widths).
 
     PYTHONPATH=src python -m repro.launch.train \
         --arch distilbert-mlm --clients 8 --skew length --rounds 15 --ffdapt \
@@ -13,8 +13,10 @@ runs the full config with the production mesh.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
@@ -26,14 +28,15 @@ from repro.core.ffdapt import FFDAPTConfig
 from repro.core.noniid import make_client_datasets
 from repro.core.rounds import FedSession, RoundPlan
 from repro.core.strategy import COMPRESSORS, STRATEGIES, make_strategy
-from repro.sim import FLEETS, make_fleet
 from repro.data.corpus import generate_corpus
+from repro.launch.cache import use_compile_cache
+from repro.sim import FLEETS, make_fleet
 from repro.models.model import init_model
 from repro.models.steps import make_eval_step
 from repro.nn import param as P
 
 
-def main() -> None:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="distilbert-mlm")
     ap.add_argument("--clients", type=int, default=2,
@@ -155,20 +158,39 @@ def main() -> None:
     ap.add_argument("--jax-profile", default="",
                     help="also capture a jax.profiler device trace into "
                          "this directory (TensorBoard/xprof format)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.resume and not args.ckpt_dir:
         ap.error("--resume requires --ckpt-dir")
+    if args.param_space in ("lora", "adapter") and args.ffdapt:
+        ap.error(f"--param-space {args.param_space} does not compose "
+                 f"with --ffdapt (both claim the update mask)")
+    if args.param_space == "frozen_window" and not args.ffdapt:
+        ap.error("--param-space frozen_window names the --ffdapt "
+                 "schedule — pass --ffdapt (with --gamma/--epsilon) too")
+    return args
 
-    from repro import obs
-    if args.trace_out:
-        obs.enable()
-        obs.capture_compiles()
 
+@dataclasses.dataclass
+class Job:
+    """Everything ``main`` builds from its arguments before the session
+    runs: ``FedSession(job.cfg, job.optimizer, job.plan).run(job.params,
+    job.batches, resume=args.resume)`` is the run."""
+
+    cfg: Any
+    optimizer: Any
+    plan: RoundPlan
+    params: Any
+    batches: Any
+    ds: Optional[Dict[str, Any]]        # None under --client-pool
+    held_docs: List[Any]
+
+
+def build(args: argparse.Namespace) -> Job:
+    """Config, synthetic client data (from ``--seed``), initial params and
+    the round plan, exactly as the CLI runs them."""
     cfg = get_config(args.arch)
     if not args.full_config:
         cfg = cfg.reduced()
-    print(f"arch={cfg.name} ({cfg.arch_type}) layers={cfg.n_layers} "
-          f"d={cfg.d_model} vocab={cfg.vocab_size}")
 
     from repro.data.corpus import split_holdout
     docs, held_docs = split_holdout(generate_corpus(args.docs, seed=args.seed))
@@ -181,8 +203,6 @@ def main() -> None:
                                    seed=args.seed,
                                    limit=args.max_steps_per_round)
         sizes = batches.sizes
-        print(f"client pool: {args.clients:,} virtual clients over "
-              f"{args.client_pool} lazily-built data shards")
     else:
         ds = make_client_datasets(docs, cfg, k=args.clients, skew=args.skew,
                                   batch=args.batch_size, seq=args.seq_len,
@@ -191,12 +211,8 @@ def main() -> None:
         if args.max_steps_per_round:
             batches = [b[:args.max_steps_per_round] for b in batches]
         sizes = ds["sizes"]
-        print("per-client local steps:", [len(b) for b in batches])
-        print("data skew sigmas:", json.dumps(
-            {k: round(v["sigma"], 2) for k, v in ds["stats"].items()}))
 
     params = P.unbox(init_model(jax.random.PRNGKey(args.seed), cfg))
-    print(f"params: {sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params)):,}")
 
     strategy = make_strategy(args.strategy, compress=args.compress,
                              mu=args.mu, beta=args.server_beta,
@@ -208,13 +224,6 @@ def main() -> None:
             args.param_space, rank=args.lora_rank, alpha=args.lora_alpha,
             adapter_dim=args.adapter_dim,
             targets=tuple(t for t in args.peft_targets.split(",") if t))
-        if pspace.low_rank and args.ffdapt:
-            ap.error(f"--param-space {args.param_space} does not compose "
-                     f"with --ffdapt (both claim the update mask)")
-        if pspace.kind == "frozen_window" and not args.ffdapt:
-            ap.error("--param-space frozen_window names the --ffdapt "
-                     "schedule — pass --ffdapt (with --gamma/--epsilon) too")
-        print(f"param space: {pspace.to_json()}")
     plan = RoundPlan(n_rounds=args.rounds, engine=args.engine,
                      strategy=strategy,
                      cohort_shard=args.cohort_shard or None,
@@ -243,6 +252,34 @@ def main() -> None:
                          "client_pool": args.client_pool,
                          "fleet": args.fleet, "calibrated": args.calibrated,
                          "sim_seed": args.sim_seed})
+    return Job(cfg, optim.adam(args.lr), plan, params, batches, ds,
+               held_docs)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    use_compile_cache()
+
+    from repro import obs
+    if args.trace_out:
+        obs.enable()
+        obs.capture_compiles()
+
+    job = build(args)
+    cfg, plan, params, ds = job.cfg, job.plan, job.params, job.ds
+    strategy = plan.strategy
+    print(f"arch={cfg.name} ({cfg.arch_type}) layers={cfg.n_layers} "
+          f"d={cfg.d_model} vocab={cfg.vocab_size}")
+    if ds is None:
+        print(f"client pool: {args.clients:,} virtual clients over "
+              f"{args.client_pool} lazily-built data shards")
+    else:
+        print("per-client local steps:", [len(b) for b in job.batches])
+        print("data skew sigmas:", json.dumps(
+            {k: round(v["sigma"], 2) for k, v in ds["stats"].items()}))
+    print(f"params: {sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params)):,}")
+    if plan.param_space is not None:
+        print(f"param space: {plan.param_space.to_json()}")
     shard_note = (f" cohort_shard={args.cohort_shard}"
                   if args.cohort_shard else "")
     print(f"strategy={strategy.name} engine={args.engine} "
@@ -254,8 +291,8 @@ def main() -> None:
                  else "no checkpoint on disk, starting fresh"))
     t0 = time.perf_counter()
     with obs.jax_profile(args.jax_profile or None):
-        params, hist = FedSession(cfg, optim.adam(args.lr), plan).run(
-            params, batches, resume=args.resume)
+        params, hist = FedSession(cfg, job.optimizer, plan).run(
+            params, job.batches, resume=args.resume)
     wall = time.perf_counter() - t0
 
     for h in hist:
@@ -338,7 +375,7 @@ def main() -> None:
     stopped_early = args.stop_after and args.stop_after < args.rounds
     if not stopped_early:
         eval_step = jax.jit(make_eval_step(cfg))
-        heldout = make_client_datasets(held_docs,
+        heldout = make_client_datasets(job.held_docs,
                                        cfg, k=1, batch=args.batch_size,
                                        seq=args.seq_len)["batches"][0][:4]
         losses = [float(eval_step(params, b)["loss"]) for b in heldout]

@@ -4,15 +4,17 @@ Two independent capture layers on top of the span tracer:
 
   * ``jax_profile(outdir)`` — context manager around ``jax.profiler.trace``
     (TensorBoard/XProf format, device-level detail).  ``outdir=None`` is a
-    no-op, so drivers can wire it unconditionally; a missing/broken
-    profiler degrades to the no-op with a warning instead of killing the
-    run (the container may lack libtpu/profiler support).
+    no-op, so callers can wire it unconditionally; a profiler that cannot
+    start raises, so a run asked for a device trace never ends without
+    one.
 
   * ``capture_compiles()`` — registers a ``jax.monitoring`` listener that
     turns every ``/jax/core/compile/*`` duration event (jaxpr trace, MLIR
     lowering, backend compile) into a span on the process-wide tracer
     (category ``compile``) and bumps ``compile.events`` /
-    ``compile.total_s`` in the metrics registry.  Compile time is the #1
+    ``compile.total_s`` plus one ``compile.<phase>_s`` per phase
+    (``compile.backend_compile_s`` is the part a persistent-cache hit
+    saves) in the metrics registry.  Compile time is the #1
     confound in round-time drift — a retrace shows up as a fat span right
     where the round got slow instead of as an unexplained 30s ratio spike.
 
@@ -32,6 +34,7 @@ from repro.obs.metrics import registry as _registry
 from repro.obs.trace import PID_MEASURED, get_tracer
 
 _COMPILE_LISTENER_INSTALLED = False
+_COMPILE_PREFIX = "/jax/core/compile/"
 
 
 def record_compile(what: str, **args: Any) -> None:
@@ -48,14 +51,17 @@ def record_compile(what: str, **args: Any) -> None:
 def _on_duration_event(event: str, duration_secs: float, **kw: Any) -> None:
     """jax.monitoring listener: compile-phase durations -> tracer spans.
     The event fires at phase END, so the span is backdated by its own
-    duration; non-compile events are ignored."""
-    if "compile" not in event:
+    duration.  Only ``/jax/core/compile/*`` phases count: the persistent
+    cache's ``compile_time_saved_sec`` names time NOT spent compiling."""
+    if not event.startswith(_COMPILE_PREFIX):
         return
-    name = event.rsplit("/", 1)[-1]
+    name = event[len(_COMPILE_PREFIX):]
     if name.endswith("_duration"):
         name = name[: -len("_duration")]
+    secs = max(duration_secs, 0.0)
     _registry().counter("compile.events").inc()
-    _registry().counter("compile.total_s").inc(max(duration_secs, 0.0))
+    _registry().counter("compile.total_s").inc(secs)
+    _registry().counter(f"compile.{name}_s").inc(secs)
     tracer = get_tracer()
     if not tracer.enabled:
         return
@@ -65,20 +71,15 @@ def _on_duration_event(event: str, duration_secs: float, **kw: Any) -> None:
                     tid=0)
 
 
-def capture_compiles() -> bool:
-    """Install the compile-event listener (idempotent).  Returns True when
-    the listener is active; False when this jax build has no
-    ``jax.monitoring`` duration events to subscribe to."""
+def capture_compiles() -> None:
+    """Install the compile-event listener (idempotent).  Raises when this
+    jax build has no ``jax.monitoring`` duration events to subscribe to."""
     global _COMPILE_LISTENER_INSTALLED
     if _COMPILE_LISTENER_INSTALLED:
-        return True
-    try:
-        from jax import monitoring
-        monitoring.register_event_duration_secs_listener(_on_duration_event)
-    except Exception:
-        return False
+        return
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_duration_event)
     _COMPILE_LISTENER_INSTALLED = True
-    return True
 
 
 @contextlib.contextmanager
@@ -86,17 +87,10 @@ def jax_profile(outdir: Optional[str]) -> Iterator[None]:
     """``with jax_profile(dir):`` wraps the body in a ``jax.profiler``
     trace written to ``dir`` (viewable in TensorBoard / xprof / Perfetto).
     ``outdir`` of None/"" is a no-op; a profiler that fails to start
-    degrades to the no-op with a warning (some hosts lack the backend)."""
+    raises."""
     if not outdir:
         yield
         return
-    try:
-        import jax.profiler as jp
-        ctx = jp.trace(outdir)
-    except Exception as e:                        # pragma: no cover
-        print(f"obs.profile: jax profiler unavailable ({e}); "
-              f"continuing without device trace")
-        yield
-        return
-    with ctx:
+    import jax.profiler as jp
+    with jp.trace(outdir):
         yield

@@ -28,8 +28,11 @@ within a few percent.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 from typing import Dict, List, Optional, Tuple
+
+import numpy as np
 
 from repro.telemetry.hlo import (Computation, Op, called_computations,
                                  entry_name, parse_computations, shape_bytes,
@@ -106,8 +109,8 @@ def _elements(text: str) -> int:
 
 
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
-_DIM_LABELS_RE = re.compile(r"dim_labels=[\w?]+_([\w?]+)->")
-_GROUPS_RE = re.compile(r"feature_group_count=(\d+)")
+_DIM_LABELS_RE = re.compile(r"dim_labels=([\w?]+)_([\w?]+)->([\w?]+)")
+_WINDOW_RE = re.compile(r"window=\{([^}]*)\}")
 
 
 def dot_flops(op: Op, comp: Computation) -> float:
@@ -125,23 +128,56 @@ def dot_flops(op: Op, comp: Computation) -> float:
     return 2.0 * out_n * k
 
 
+@functools.lru_cache(maxsize=None)
+def _conv_taps(n_in: int, n_out: int, size: int, stride: int, pad_lo: int,
+               lhs_dilate: int, rhs_dilate: int) -> int:
+    """Window taps of one spatial dim that land on a real lhs element,
+    summed over the dim's output positions.  Taps on padding or on the holes
+    of an lhs dilation multiply zeros and cost nothing."""
+    pos = np.arange(n_out) * stride - pad_lo
+    last = (n_in - 1) * lhs_dilate
+    total = 0
+    for w in range(size):
+        q = pos + w * rhs_dilate
+        total += int(np.count_nonzero((q >= 0) & (q <= last)
+                                      & (q % lhs_dilate == 0)))
+    return total
+
+
 def conv_flops(op: Op, comp: Computation) -> float:
-    """2 x result elements x (kernel elements / output features) / groups:
-    each output element contracts the kernel's spatial x input-feature dims."""
-    out_n = _elements(op.result)
-    kdims = shape_dims(comp.operand_type(op, 1))
-    if not kdims:
-        return 2.0 * out_n
-    k = 1
-    for d in kdims:
-        k *= d
+    """2 x output batch x output features x input features per group x the
+    taps that hit real input, summed over output positions.
+
+    Counting taps instead of window elements matters on TPU, where XLA
+    writes a batched matmul as a convolution whose window walks an
+    lhs-dilated batch axis (``window={size=32 stride=31 lhs_dilate=32}``):
+    each output position meets one real element, not 32."""
+    out_dims = shape_dims(op.result)
+    lhs_dims = shape_dims(comp.operand_type(op, 0))
+    rhs_dims = shape_dims(comp.operand_type(op, 1))
     m = _DIM_LABELS_RE.search(op.rest)
-    if m and "o" in m.group(1) and m.group(1).index("o") < len(kdims):
-        k //= max(kdims[m.group(1).index("o")], 1)
-    g = _GROUPS_RE.search(op.rest)
-    if g:
-        k //= max(int(g.group(1)), 1)
-    return 2.0 * out_n * max(k, 1)
+    if not (m and out_dims and lhs_dims and rhs_dims):
+        return 2.0 * _elements(op.result)
+    lhs_l, rhs_l, out_l = m.groups()
+    wm = _WINDOW_RE.search(op.rest)
+    win = {}
+    for item in (wm.group(1).split() if wm else ()):
+        key, _, val = item.partition("=")
+        win[key] = val.split("x")
+
+    def attr(key: str, j: int, default: int) -> int:
+        return int(win[key][j]) if key in win else default
+
+    taps = 1
+    for j in range(sum(c.isdigit() for c in out_l)):
+        c = str(j)
+        pad_lo = int(win["pad"][j].split("_")[0]) if "pad" in win else 0
+        taps *= _conv_taps(lhs_dims[lhs_l.index(c)],
+                           out_dims[out_l.index(c)], attr("size", j, 1),
+                           attr("stride", j, 1), pad_lo,
+                           attr("lhs_dilate", j, 1), attr("rhs_dilate", j, 1))
+    return (2.0 * out_dims[out_l.index("b")] * out_dims[out_l.index("f")]
+            * rhs_dims[rhs_l.index("i")] * taps)
 
 
 def op_flops(op: Op, comp: Computation) -> float:

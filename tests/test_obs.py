@@ -189,6 +189,36 @@ def test_registry_semantics(tmp_path):
     assert rows[2]["p99"] == pytest.approx(3.97)
 
 
+def test_compile_listener_counts_compile_phases_only():
+    """A persistent-cache hit reports the seconds it saved under a name
+    that holds "compile"; those were not spent compiling."""
+    from repro.obs.profile import _on_duration_event
+    _on_duration_event("/jax/core/compile/jaxpr_trace_duration", 0.25)
+    _on_duration_event("/jax/core/compile/backend_compile_duration", 1.5)
+    _on_duration_event("/jax/compilation_cache/compile_time_saved_sec", 30.0)
+    _on_duration_event("/jax/compilation_cache/cache_retrieval_time_sec", 0.1)
+    reg = obs.registry()
+    assert reg.counter("compile.events").value == 2
+    assert reg.counter("compile.total_s").value == 1.75
+    assert reg.counter("compile.backend_compile_s").value == 1.5
+    assert reg.counter("compile.jaxpr_trace_s").value == 0.25
+
+
+def test_jax_profile_raises_when_the_profiler_cannot_start(monkeypatch,
+                                                            tmp_path):
+    import jax.profiler
+
+    def broken(*a, **kw):
+        raise RuntimeError("no profiler backend")
+
+    monkeypatch.setattr(jax.profiler, "trace", broken)
+    with obs.jax_profile(None):             # off: never touches the profiler
+        pass
+    with pytest.raises(RuntimeError, match="no profiler backend"):
+        with obs.jax_profile(str(tmp_path)):
+            pass
+
+
 # ---------------------------------------------------------------------------
 # Drift
 # ---------------------------------------------------------------------------

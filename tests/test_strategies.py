@@ -129,7 +129,7 @@ def _distinct_magnitudes(seed, n):
     return jnp.asarray(rng.permutation(mags * signs), jnp.float32)
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(n=st.integers(min_value=1, max_value=97),
        frac=st.floats(min_value=0.01, max_value=1.0),
        seed=st.integers(min_value=0, max_value=2**31))
@@ -175,7 +175,7 @@ def test_topk_tie_stability():
     assert ST.topk_bytes(d, 0.3) == 2 * 8          # static law stays at k
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(n=st.integers(min_value=1, max_value=257),
        scale_exp=st.integers(min_value=-6, max_value=4),
        seed=st.integers(min_value=0, max_value=2**31))

@@ -187,6 +187,36 @@ def test_parse_op_operand_types_from_symtab():
     assert T.dot_flops(op, comp) == pytest.approx(2.0 * 4 * 4 * 9)
 
 
+def _conv(result, lhs, rhs, attrs):
+    op = T.parse_op(f"  %c = {result} convolution({lhs} %a, {rhs} %b), "
+                    + attrs)
+    return T.conv_flops(op, T.Computation("c", [op], {}))
+
+
+@pytest.mark.parametrize("result,lhs,rhs,attrs,want", [
+    # the TPU compiler's batched matmul (scores of 32x12 (128,64)@(64,128)
+    # products): the window walks an lhs-dilated batch axis and meets one
+    # real element per output position
+    ("f32[32,12,128,128]", "f32[32,128,12,64]", "f32[32,128,12,64]",
+     "window={size=32x12 stride=31x11 lhs_dilate=32x12}, "
+     "dim_labels=0b1f_0o1i->01bf", 2.0 * 32 * 12 * 128 * 128 * 64),
+    # a projection whose head axis is a padded window over a size-1 dim
+    ("f32[32,128,12,64]", "f32[32,768,128,1]", "f32[768,12,64,1]",
+     "window={size=1x12 pad=0_0x11_11 rhs_reversal=0x1}, "
+     "dim_labels=0fb1_i1o0->0b1f", 2.0 * 32 * 128 * 12 * 64 * 768),
+    # plain 1-D conv, 'same' padding: the edge positions have 2 taps, not 3
+    ("f32[1,10,5]", "f32[1,10,4]", "f32[3,4,5]",
+     "window={size=3 pad=1_1}, dim_labels=b0f_0io->b0f",
+     2.0 * 5 * 4 * (2 + 3 * 8 + 2)),
+    # depthwise: one input feature per group
+    ("f32[1,10,4]", "f32[1,10,4]", "f32[3,1,4]",
+     "window={size=3 pad=1_1}, dim_labels=b0f_0io->b0f, "
+     "feature_group_count=4", 2.0 * 4 * 1 * (2 + 3 * 8 + 2)),
+])
+def test_conv_flops_counts_taps_on_real_input(result, lhs, rhs, attrs, want):
+    assert _conv(result, lhs, rhs, attrs) == pytest.approx(want)
+
+
 # ---------------------------------------------------------------------------
 # RoundResult ledger: both engines, and agreement with XLA cost_analysis
 # ---------------------------------------------------------------------------

@@ -82,11 +82,10 @@ def rel_gap(a: float, b: float) -> float:
 
 
 class Compiles:
-    """Compile events, their seconds, and the backend-compile part of those
-    seconds (what a persistent-cache hit saves) since the last ``take``,
-    from the obs compile counters."""
+    """Compile events and backend-compile seconds (what a persistent-cache
+    hit saves) since the last ``take``, from the obs compile counters."""
 
-    NAMES = ("compile.events", "compile.total_s", "compile.backend_compile_s")
+    NAMES = ("compile.events", "compile.backend_compile_s")
 
     def __init__(self, obs):
         self._reg = obs.registry()
@@ -94,10 +93,9 @@ class Compiles:
 
     def take(self) -> dict:
         now = tuple(self._reg.counter(n).value for n in self.NAMES)
-        ev, sec, backend = (n - l for n, l in zip(now, self._last))
+        ev, backend = (n - l for n, l in zip(now, self._last))
         self._last = now
-        return {"compiles": int(ev), "compile_s": f"{sec:.2f}",
-                "backend_compile_s": f"{backend:.2f}"}
+        return {"compiles": int(ev), "backend_compile_s": f"{backend:.2f}"}
 
 
 def device_gate() -> jax.Device:
@@ -232,7 +230,6 @@ def main() -> None:
         bytes_limit=stats.get("bytes_limit"))
     reg = obs.registry()
     log("total", seconds=f"{time.perf_counter() - t0:.1f}",
-        compile_s=f"{reg.counter('compile.total_s').value:.2f}",
         backend_compile_s=f"{reg.counter('compile.backend_compile_s').value:.2f}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -2,7 +2,8 @@
 # Observability smoke: one tiny train run and one tiny serve run with every
 # obs flag on, then hold the emitted artifacts to the acceptance bar:
 #   * the Chrome trace is valid JSON carrying the expected measured spans
-#     (round/dispatch/aggregate/checkpoint, admit/decode), compile events,
+#     (round/dispatch/stack/launch/combine/wait/account/checkpoint,
+#     admit/decode), compile events,
 #     AND the synthetic simulated timeline (sim.round/sim.client);
 #   * the drift ledger has exactly one row per round, each priced by the
 #     fleet predictor with a finite ratio;
@@ -43,7 +44,8 @@ trace = json.load(open(f"{tmp}/train_trace.json"))
 assert trace.get("displayTimeUnit") == "ms", "not a Chrome trace payload"
 events = trace["traceEvents"]
 names = {e.get("name") for e in events}
-for want in ("train.round", "train.dispatch", "train.aggregate",
+for want in ("train.round", "train.prepare", "train.dispatch", "train.stack",
+             "train.launch", "train.combine", "train.wait", "train.account",
              "train.checkpoint", "sim.round", "sim.client"):
     assert want in names, f"train trace missing span {want!r}"
 assert any(n and n.startswith("compile/") for n in names), \

@@ -56,6 +56,15 @@ tests/test_resume.py).  Checkpointing happens at round boundaries, where
 the paper's schedule holds no optimizer state (optimizers re-init each
 round), so params + server state + RNG + pointer IS the whole run state.
 
+Both engines record the same ``repro.obs`` spans per round: ``train.round``
+holds ``train.prepare`` (sampling the cohort and setting up the round's
+weights and accumulators), ``train.dispatch`` (one per shard, or per client
+when sequential;
+inside it ``train.stack`` materializes the batches and ``train.launch``
+calls the program), then ``train.combine`` (the fold's combine launch) and
+``train.wait`` (``block_until_ready``); ``train.account`` follows the
+round: the host's accounting through the checkpoint.
+
 Per the paper (Appendix E.1): optimizers are re-initialized at the start of
 each round's local training; 1 local epoch per round; 15 rounds.
 """
@@ -80,6 +89,7 @@ from repro.nn import param as P
 from repro.peft.space import ParamSpace, frozen_shippable_template
 from repro.obs.metrics import registry as _obs_registry
 from repro.obs.profile import record_compile
+from repro.obs.trace import NULL_SPAN as _NULL_SPAN
 from repro.obs.trace import span as _obs_span
 from repro.telemetry import batch_struct, client_step_cost
 
@@ -299,6 +309,14 @@ def _stack_shard(data, ids: Sequence[int], max_steps: int):
         padded = [bs[i % len(bs)] for i in range(max_steps)]
         per_client.append(jax.tree.map(lambda *xs: jnp.stack(xs), *padded))
     return jax.tree.map(lambda *xs: jnp.stack(xs), *per_client)
+
+
+def _footprint(tree) -> Dict[str, int]:
+    """``arrays`` and ``bytes`` of a pytree's leaves: the args a
+    ``train.stack`` span gives what it hands on."""
+    leaves = jax.tree.leaves(tree)
+    return {"arrays": len(leaves),
+            "bytes": sum(int(x.nbytes) for x in leaves)}
 
 
 def _record_round_metrics(rr: "RoundResult") -> None:
@@ -656,8 +674,9 @@ class FedSession:
             with _obs_span("train.round", cat="train", round=t,
                            engine="sequential"):
                 t0 = time.perf_counter()
-                part = _participants(rng, len(data), plan.participation)
-                down = strategy.download_bytes(params, len(part))
+                with _obs_span("train.prepare", cat="train"):
+                    part = _participants(rng, len(data), plan.participation)
+                    down = strategy.download_bytes(params, len(part))
                 locals_, losses, tokens = [], [], 0.0
                 flops_e = hbm_e = coll_e = 0.0
                 c_steps, c_flops, c_hbm = [], [], []
@@ -665,73 +684,84 @@ class FedSession:
                     frozen = None
                     if windows is not None:
                         frozen = ffd.window_mask(n_units, windows[t][k])
-                    bs_k = data.batches_for(k)
-                    steps_k = len(bs_k)
-                    c_steps.append(steps_k)
-                    if plan.telemetry:
-                        cost = self._step_cost(bs_k[0], frozen=frozen)
-                        c_flops.append(cost.flops)
-                        c_hbm.append(cost.hbm_bytes)
-                        flops_e += cost.flops * steps_k
-                        hbm_e += cost.hbm_bytes * steps_k
-                        coll_e += cost.collective_bytes * steps_k
-                    opt_state = P.unbox(optimizer.init(params))
-                    extra = (base,) if peft else ()
-                    if strategy.needs_anchor:
-                        extra += (params,)   # round-global anchor (the bank,
-                                             # under low-rank — FedProx pulls
-                                             # toward the global subspace)
                     # dispatch span = one client's whole local epoch (the
                     # sequential engine's unit of dispatch); jit calls sync
-                    # per batch, so this measures real compute
+                    # per batch, so its launch child measures real compute
                     with _obs_span("train.dispatch", cat="train", round=t,
-                                   client=k, steps=steps_k):
-                        p_k, _, loss, tok = _epoch(self._step_for(frozen),
-                                                   params, opt_state, bs_k,
-                                                   *extra)
+                                   client=k):
+                        with _obs_span("train.stack", cat="train") as sp:
+                            bs_k = data.batches_for(k)
+                            if sp is not _NULL_SPAN:
+                                sp.set(**_footprint(bs_k))
+                        steps_k = len(bs_k)
+                        c_steps.append(steps_k)
+                        if plan.telemetry:
+                            cost = self._step_cost(bs_k[0], frozen=frozen)
+                            c_flops.append(cost.flops)
+                            c_hbm.append(cost.hbm_bytes)
+                            flops_e += cost.flops * steps_k
+                            hbm_e += cost.hbm_bytes * steps_k
+                            coll_e += cost.collective_bytes * steps_k
+                        opt_state = P.unbox(optimizer.init(params))
+                        extra = (base,) if peft else ()
+                        if strategy.needs_anchor:
+                            extra += (params,)   # round-global anchor (the
+                                                 # bank, under low-rank —
+                                                 # FedProx pulls toward the
+                                                 # global subspace)
+                        with _obs_span("train.launch", cat="train",
+                                       steps=steps_k):
+                            p_k, _, loss, tok = _epoch(
+                                self._step_for(frozen), params, opt_state,
+                                bs_k, *extra)
                     locals_.append(p_k)
                     losses.append(loss)
                     tokens += tok
-                with _obs_span("train.aggregate", cat="train", round=t,
+                with _obs_span("train.combine", cat="train", round=t,
                                clients=len(part)):
                     params, state, nbytes = strategy.aggregate(
                         params, locals_, [sizes[k] for k in part], state)
+                with _obs_span("train.wait", cat="train", round=t):
+                    jax.block_until_ready(params)
                 dt = time.perf_counter() - t0
-            if windows is not None:
-                # FFDAPT accounting fix: clients ship only their unfrozen
-                # layer rows, so the round total is the sum of per-client
-                # subspace prices — not the aggregate()'s full-tree figure
-                c_up, nbytes = self._client_upload_bytes(
-                    params, part, windows, n_units, t)
-            else:
-                # aggregate() reports the exact round total; per-client
-                # shares are the static even split + remainder (Compressed
-                # tie-keeps can skew individual clients by a few entries,
-                # but the shares always sum to the exact round total)
-                c_up = split_bytes(nbytes, len(part))
-            rr = RoundResult(
-                t, float(np.mean(losses)), dt,
-                windows[t] if windows else None,
-                upload_bytes=nbytes, tokens=tokens,
-                tokens_per_s=tokens / max(dt, 1e-9), clients=part,
-                flops_estimate=flops_e, hbm_bytes_estimate=hbm_e,
-                comm_bytes=down + nbytes + int(coll_e),
-                download_bytes=down, client_steps=c_steps,
-                client_step_flops=c_flops or None,
-                client_step_hbm=c_hbm or None,
-                client_upload_bytes=c_up)
-            if fleet is not None:
-                from repro.sim.clock import sync_round_s
-                rr.sim_round_s = sync_round_s(rr, fleet,
-                                              overlap=plan.overlap)
-            if plan.eval_fn is not None:
-                rr.eval_loss = float(plan.eval_fn(
-                    space.merge(base, params) if peft else params))
-            history.append(rr)
-            _record_round_metrics(rr)
-            self._checkpoint(t, {"base": base, "peft": params} if peft
-                             else params, state, rng, history, windows,
-                             n_units)
+            with _obs_span("train.account", cat="train", round=t):
+                if windows is not None:
+                    # FFDAPT accounting fix: clients ship only their
+                    # unfrozen layer rows, so the round total is the sum of
+                    # per-client subspace prices — not the aggregate()'s
+                    # full-tree figure
+                    c_up, nbytes = self._client_upload_bytes(
+                        params, part, windows, n_units, t)
+                else:
+                    # aggregate() reports the exact round total; per-client
+                    # shares are the static even split + remainder
+                    # (Compressed tie-keeps can skew individual clients by
+                    # a few entries, but the shares always sum to the exact
+                    # round total)
+                    c_up = split_bytes(nbytes, len(part))
+                rr = RoundResult(
+                    t, float(np.mean(losses)), dt,
+                    windows[t] if windows else None,
+                    upload_bytes=nbytes, tokens=tokens,
+                    tokens_per_s=tokens / max(dt, 1e-9), clients=part,
+                    flops_estimate=flops_e, hbm_bytes_estimate=hbm_e,
+                    comm_bytes=down + nbytes + int(coll_e),
+                    download_bytes=down, client_steps=c_steps,
+                    client_step_flops=c_flops or None,
+                    client_step_hbm=c_hbm or None,
+                    client_upload_bytes=c_up)
+                if fleet is not None:
+                    from repro.sim.clock import sync_round_s
+                    rr.sim_round_s = sync_round_s(rr, fleet,
+                                                  overlap=plan.overlap)
+                if plan.eval_fn is not None:
+                    rr.eval_loss = float(plan.eval_fn(
+                        space.merge(base, params) if peft else params))
+                history.append(rr)
+                _record_round_metrics(rr)
+                self._checkpoint(t, {"base": base, "peft": params} if peft
+                                 else params, state, rng, history, windows,
+                                 n_units)
         return (space.merge(base, params) if peft else params), history
 
     # -----------------------------------------------------------------
@@ -836,9 +866,11 @@ class FedSession:
             # resolves its fresh path on it); participation keeps m constant
             # across rounds, so this compiles once per session
             if m not in combine_cache:
-                combine_cache[m] = jax.jit(
-                    lambda gp, pa, st: strategy.aggregate_combine(
-                        gp, pa, st, k=m))
+                def _fed_combine(gp, pa, st):
+                    return strategy.aggregate_combine(gp, pa, st, k=m)
+                combine_cache[m] = jax.jit(_fed_combine)
+                # like ``shard_program``: the combine the rounds run
+                self.combine_program = combine_cache[m]
             return combine_cache[m]
 
         rng = np.random.default_rng(plan.seed) if rng is None else rng
@@ -860,31 +892,37 @@ class FedSession:
             with _obs_span("train.round", cat="train", round=t,
                            engine="parallel"):
                 t0 = time.perf_counter()
-                part = _participants(rng, K, plan.participation)
-                m = len(part)
-                w = w_all if m == K else w_all[jnp.asarray(part, jnp.int32)]
-                w_agg, w_loss = norm_weights(w)
-                partial = strategy.aggregate_init(params)
-                loss_acc = jnp.zeros((), jnp.float32)
-                tok_acc = jnp.zeros((), jnp.float32)
+                with _obs_span("train.prepare", cat="train"):
+                    part = _participants(rng, K, plan.participation)
+                    m = len(part)
+                    w = (w_all if m == K
+                         else w_all[jnp.asarray(part, jnp.int32)])
+                    w_agg, w_loss = norm_weights(w)
+                    partial = strategy.aggregate_init(params)
+                    loss_acc = jnp.zeros((), jnp.float32)
+                    tok_acc = jnp.zeros((), jnp.float32)
                 off = 0
                 for si, width in enumerate(_shard_widths(m,
                                                          plan.cohort_shard)):
                     ids = part[off:off + width]
-                    # dispatch span = shard materialization + the async jit
-                    # dispatch (device work may still be in flight when it
-                    # closes; the round span is bounded by block_until_ready)
+                    # dispatch span = shard materialization (train.stack)
+                    # + the async jit dispatch (train.launch); device work
+                    # may still be in flight when it closes, and the round
+                    # waits for it in train.wait
                     with _obs_span("train.dispatch", cat="train", round=t,
                                    shard=si, width=width):
-                        bsub = _stack_shard(data, ids, max_steps)
-                        if windows is not None:
-                            fmasks = jnp.stack([
-                                jnp.asarray(ffd.window_mask(n_units,
-                                                            windows[t][k]),
-                                            jnp.float32) for k in ids])
-                        else:
-                            fmasks = jnp.zeros((len(ids), n_units),
-                                               jnp.float32)
+                        with _obs_span("train.stack", cat="train") as sp:
+                            bsub = _stack_shard(data, ids, max_steps)
+                            if sp is not _NULL_SPAN:
+                                sp.set(**_footprint(bsub))
+                            if windows is not None:
+                                fmasks = jnp.stack([
+                                    jnp.asarray(ffd.window_mask(
+                                        n_units, windows[t][k]),
+                                        jnp.float32) for k in ids])
+                            else:
+                                fmasks = jnp.zeros((len(ids), n_units),
+                                                   jnp.float32)
                         args = (params, base, partial, loss_acc, tok_acc,
                                 bsub, fmasks, w_agg[off:off + width],
                                 w_loss[off:off + width])
@@ -892,53 +930,58 @@ class FedSession:
                             self.shard_args = jax.tree.map(
                                 lambda x: jax.ShapeDtypeStruct(x.shape,
                                                                x.dtype), args)
-                        partial, loss_acc, tok_acc = fed_shard(*args)
+                        with _obs_span("train.launch", cat="train"):
+                            partial, loss_acc, tok_acc = fed_shard(*args)
                     off += width
-                with _obs_span("train.aggregate", cat="train", round=t,
+                with _obs_span("train.combine", cat="train", round=t,
                                clients=m):
                     params, state = _combine_for(m)(params, partial, state)
-                    loss, toks = loss_acc, tok_acc
+                loss, toks = loss_acc, tok_acc
+                with _obs_span("train.wait", cat="train", round=t):
                     # async dispatch would under-time the round
                     jax.block_until_ready(loss)
                 dt = time.perf_counter() - t0
-            toks = float(toks)
-            c_up, nbytes = self._client_upload_bytes(params, part, windows,
-                                                     n_units, t)
-            # rectangular schedule: every participant runs max_steps steps
-            # (short clients cycle their data), so the ledger multiplies the
-            # single analyzed program by steps x participants
-            n_steps = max_steps * len(part)
-            down = strategy.download_bytes(params, len(part))
-            rr = RoundResult(
-                t, float(loss), dt, windows[t] if windows else None,
-                upload_bytes=nbytes,
-                tokens=toks, tokens_per_s=toks / max(dt, 1e-9), clients=part,
-                flops_estimate=(step_cost.flops * n_steps
-                                if step_cost else 0.0),
-                hbm_bytes_estimate=(step_cost.hbm_bytes * n_steps
+            with _obs_span("train.account", cat="train", round=t):
+                toks = float(toks)
+                c_up, nbytes = self._client_upload_bytes(params, part,
+                                                         windows, n_units, t)
+                # rectangular schedule: every participant runs max_steps
+                # steps (short clients cycle their data), so the ledger
+                # multiplies the single analyzed program by steps x
+                # participants
+                n_steps = max_steps * len(part)
+                down = strategy.download_bytes(params, len(part))
+                rr = RoundResult(
+                    t, float(loss), dt, windows[t] if windows else None,
+                    upload_bytes=nbytes,
+                    tokens=toks, tokens_per_s=toks / max(dt, 1e-9),
+                    clients=part,
+                    flops_estimate=(step_cost.flops * n_steps
                                     if step_cost else 0.0),
-                comm_bytes=(down + nbytes
-                            + int(step_cost.collective_bytes * n_steps
-                                  if step_cost else 0)),
-                download_bytes=down,
-                client_steps=[max_steps] * len(part),
-                client_step_flops=([step_cost.flops] * len(part)
-                                   if step_cost else None),
-                client_step_hbm=([step_cost.hbm_bytes] * len(part)
-                                 if step_cost else None),
-                client_upload_bytes=c_up)
-            if fleet is not None:
-                from repro.sim.clock import sync_round_s
-                rr.sim_round_s = sync_round_s(rr, fleet,
-                                              overlap=plan.overlap)
-            if plan.eval_fn is not None:
-                rr.eval_loss = float(plan.eval_fn(
-                    space.merge(base, params) if peft else params))
-            history.append(rr)
-            _record_round_metrics(rr)
-            self._checkpoint(t, {"base": base, "peft": params} if peft
-                             else params, state, rng, history, windows,
-                             n_units)
+                    hbm_bytes_estimate=(step_cost.hbm_bytes * n_steps
+                                        if step_cost else 0.0),
+                    comm_bytes=(down + nbytes
+                                + int(step_cost.collective_bytes * n_steps
+                                      if step_cost else 0)),
+                    download_bytes=down,
+                    client_steps=[max_steps] * len(part),
+                    client_step_flops=([step_cost.flops] * len(part)
+                                       if step_cost else None),
+                    client_step_hbm=([step_cost.hbm_bytes] * len(part)
+                                     if step_cost else None),
+                    client_upload_bytes=c_up)
+                if fleet is not None:
+                    from repro.sim.clock import sync_round_s
+                    rr.sim_round_s = sync_round_s(rr, fleet,
+                                                  overlap=plan.overlap)
+                if plan.eval_fn is not None:
+                    rr.eval_loss = float(plan.eval_fn(
+                        space.merge(base, params) if peft else params))
+                history.append(rr)
+                _record_round_metrics(rr)
+                self._checkpoint(t, {"base": base, "peft": params} if peft
+                                 else params, state, rng, history, windows,
+                                 n_units)
         return (space.merge(base, params) if peft else params), history
 
 

@@ -252,16 +252,20 @@ class FederatedStrategy:
                           norm_weights: jax.Array, partial: Any) -> Any:
         """Fold ONE shard into the carry.  ``stacked`` leaves are
         (shard, ...); ``norm_weights`` is this shard's slice of the
-        cohort-normalized weights."""
-        return fedavg_fold(partial, self.map_clients(global_params, stacked),
-                           norm_weights)
+        cohort-normalized weights.  Named scope ``fold`` on the device
+        trace, for every strategy."""
+        with jax.named_scope("fold"):
+            return fedavg_fold(partial,
+                               self.map_clients(global_params, stacked),
+                               norm_weights)
 
     def aggregate_combine(self, global_params: Any, partial: Any, state: Any,
                           *, k: int) -> Tuple[Any, Any]:
         """Finish the round: cast the fp32 carry back to param dtypes and
-        apply the strategy's server update."""
-        mean = fold_finalize(partial, global_params)
-        return self.server_update(global_params, mean, state, k=k)
+        apply the strategy's server update (named scope ``fold``)."""
+        with jax.named_scope("fold"):
+            mean = fold_finalize(partial, global_params)
+            return self.server_update(global_params, mean, state, k=k)
 
     # -- accounting ----------------------------------------------------
     def upload_bytes(self, global_params: Any, k: int) -> int:

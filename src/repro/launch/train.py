@@ -141,9 +141,12 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--trace-out", default="",
                     help="enable the span tracer and write a Chrome "
                          "trace-event JSON here (load in Perfetto / "
-                         "chrome://tracing): round/dispatch/aggregate/"
-                         "checkpoint spans, compile events, and — with "
-                         "--fleet — the simulated timeline side-by-side")
+                         "chrome://tracing): train.round with prepare, "
+                         "dispatch (stack, launch), combine and wait, then "
+                         "train.account and checkpoint spans, each with "
+                         "its parent and round; compile events, and — "
+                         "with --fleet — the simulated timeline "
+                         "side-by-side")
     ap.add_argument("--metrics-out", default="",
                     help="write the process-wide metrics registry "
                          "(counters/gauges/histograms) as JSONL here")
@@ -157,7 +160,10 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
                          "measured/predicted falls outside [1/W, W]")
     ap.add_argument("--jax-profile", default="",
                     help="also capture a jax.profiler device trace into "
-                         "this directory (TensorBoard/xprof format)")
+                         "this directory (TensorBoard/xprof format); turns "
+                         "the span tracer on, so the train.* spans appear "
+                         "on the trace's host plane next to the device "
+                         "ops, which carry the programs' named scopes")
     args = ap.parse_args(argv)
     if args.resume and not args.ckpt_dir:
         ap.error("--resume requires --ckpt-dir")
@@ -261,7 +267,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     use_compile_cache()
 
     from repro import obs
-    if args.trace_out:
+    if args.trace_out or args.jax_profile:
         obs.enable()
         obs.capture_compiles()
 

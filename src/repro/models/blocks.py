@@ -90,21 +90,27 @@ def apply_transformer_block(p, x, cfg, lc, *, mode, causal=True,
     cv = lc.get("v") if lc else None
     ci = cache_index
     if cfg.norm_position == "pre":
-        h = apply_norm(p["ln1"], x, cfg.norm_type, cfg.norm_eps)
-        a, nk, nv = apply_attention(p["attn"], h, cfg, mode=mode, causal=causal,
-                                    cache_k=ck, cache_v=cv, cache_index=ci,
-                                    positions=positions, impl=impl)
-        x = x + a
-        h = apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps)
-        m, aux = _ffn(p, h, cfg, impl)
-        x = x + m
+        with jax.named_scope("attn"):
+            h = apply_norm(p["ln1"], x, cfg.norm_type, cfg.norm_eps)
+            a, nk, nv = apply_attention(p["attn"], h, cfg, mode=mode,
+                                        causal=causal, cache_k=ck,
+                                        cache_v=cv, cache_index=ci,
+                                        positions=positions, impl=impl)
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps)
+            m, aux = _ffn(p, h, cfg, impl)
+            x = x + m
     else:  # post-norm (distilbert)
-        a, nk, nv = apply_attention(p["attn"], x, cfg, mode=mode, causal=causal,
-                                    cache_k=ck, cache_v=cv, cache_index=ci,
-                                    positions=positions, impl=impl)
-        x = apply_norm(p["ln1"], x + a, cfg.norm_type, cfg.norm_eps)
-        m, aux = _ffn(p, x, cfg, impl)
-        x = apply_norm(p["ln2"], x + m, cfg.norm_type, cfg.norm_eps)
+        with jax.named_scope("attn"):
+            a, nk, nv = apply_attention(p["attn"], x, cfg, mode=mode,
+                                        causal=causal, cache_k=ck,
+                                        cache_v=cv, cache_index=ci,
+                                        positions=positions, impl=impl)
+            x = apply_norm(p["ln1"], x + a, cfg.norm_type, cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            m, aux = _ffn(p, x, cfg, impl)
+            x = apply_norm(p["ln2"], x + m, cfg.norm_type, cfg.norm_eps)
     nlc = {"k": nk, "v": nv} if lc else None
     return x, nlc, aux
 
@@ -115,26 +121,32 @@ def apply_cross_block(p, x, cfg, lc, *, mode, kv_embeds=None, positions=None,
     embeddings (prefill/train) — at decode the projected kv live in lc."""
     gate_a = jnp.tanh(p["gate_attn"]).astype(x.dtype)
     gate_m = jnp.tanh(p["gate_mlp"]).astype(x.dtype)
-    h = apply_norm(p["lnx"], x, cfg.norm_type, cfg.norm_eps)
-    if mode == "decode" and lc and "xk" in lc:
-        # reuse projected image kv from the cache
-        from repro.nn.attention import _gqa_scores_combine, _project_qkv
-        dt = x.dtype
-        q = jnp.einsum("...d,dhk->...hk", h, p["xattn"]["wq"].astype(dt))
-        mask = jnp.zeros((1, 1, 1, lc["xk"].shape[1]), jnp.float32)
-        out = _gqa_scores_combine(q, lc["xk"].astype(dt), lc["xv"].astype(dt), mask)
-        a = jnp.einsum("...hk,hkd->...d", out, p["xattn"]["wo"].astype(dt))
-        nxk, nxv = lc["xk"], lc["xv"]
-    else:
-        a, _, _ = apply_attention(p["xattn"], h, cfg, mode="train", causal=False,
-                                  kv_x=kv_embeds, impl=impl)
-        dt = x.dtype
-        nxk = jnp.einsum("...d,dhk->...hk", kv_embeds, p["xattn"]["wk"].astype(dt))
-        nxv = jnp.einsum("...d,dhk->...hk", kv_embeds, p["xattn"]["wv"].astype(dt))
-    x = x + gate_a * a
-    h = apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps)
-    m, aux = _ffn(p, h, cfg, impl)
-    x = x + gate_m * m
+    with jax.named_scope("attn"):
+        h = apply_norm(p["lnx"], x, cfg.norm_type, cfg.norm_eps)
+        if mode == "decode" and lc and "xk" in lc:
+            # reuse projected image kv from the cache
+            from repro.nn.attention import _gqa_scores_combine
+            dt = x.dtype
+            q = jnp.einsum("...d,dhk->...hk", h, p["xattn"]["wq"].astype(dt))
+            mask = jnp.zeros((1, 1, 1, lc["xk"].shape[1]), jnp.float32)
+            out = _gqa_scores_combine(q, lc["xk"].astype(dt),
+                                      lc["xv"].astype(dt), mask)
+            a = jnp.einsum("...hk,hkd->...d", out,
+                           p["xattn"]["wo"].astype(dt))
+            nxk, nxv = lc["xk"], lc["xv"]
+        else:
+            a, _, _ = apply_attention(p["xattn"], h, cfg, mode="train",
+                                      causal=False, kv_x=kv_embeds, impl=impl)
+            dt = x.dtype
+            nxk = jnp.einsum("...d,dhk->...hk", kv_embeds,
+                             p["xattn"]["wk"].astype(dt))
+            nxv = jnp.einsum("...d,dhk->...hk", kv_embeds,
+                             p["xattn"]["wv"].astype(dt))
+        x = x + gate_a * a
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps)
+        m, aux = _ffn(p, h, cfg, impl)
+        x = x + gate_m * m
     nlc = {"xk": nxk, "xv": nxv} if lc is not None else None
     return x, nlc, aux
 
@@ -164,35 +176,42 @@ def apply_encdec_block(p, x, cfg, lc, *, mode, enc_out=None, positions=None,
     ck = lc.get("k") if lc else None
     cv = lc.get("v") if lc else None
     ci = cache_index
-    h = apply_norm(p["ln1"], x, cfg.norm_type, cfg.norm_eps)
-    a, nk, nv = apply_attention(p["attn"], h, cfg, mode=mode, causal=True,
-                                cache_k=ck, cache_v=cv, cache_index=ci,
-                                positions=positions, impl=impl)
-    x = x + a
-    h = apply_norm(p["lnx"], x, cfg.norm_type, cfg.norm_eps)
-    if mode == "decode" and lc and "xk" in lc:
-        from repro.nn.attention import _gqa_scores_combine
-        dt = x.dtype
-        q = jnp.einsum("...d,dhk->...hk", h, p["xattn"]["wq"].astype(dt))
-        if "bq" in p["xattn"]:
-            q = q + p["xattn"]["bq"].astype(dt)
-        mask = jnp.zeros((1, 1, 1, lc["xk"].shape[1]), jnp.float32)
-        out = _gqa_scores_combine(q, lc["xk"].astype(dt), lc["xv"].astype(dt), mask)
-        a = jnp.einsum("...hk,hkd->...d", out, p["xattn"]["wo"].astype(dt))
-        nxk, nxv = lc["xk"], lc["xv"]
-    else:
-        a, _, _ = apply_attention(p["xattn"], h, cfg, mode="train", causal=False,
-                                  kv_x=enc_out, impl=impl)
-        dt = x.dtype
-        nxk = jnp.einsum("...d,dhk->...hk", enc_out, p["xattn"]["wk"].astype(dt))
-        nxv = jnp.einsum("...d,dhk->...hk", enc_out, p["xattn"]["wv"].astype(dt))
-        if "bk" in p["xattn"]:
-            nxk = nxk + p["xattn"]["bk"].astype(dt)
-            nxv = nxv + p["xattn"]["bv"].astype(dt)
-    x = x + a
-    h = apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps)
-    m = apply_mlp(p["mlp"], h, cfg.mlp_type)
-    x = x + m
+    with jax.named_scope("attn"):
+        h = apply_norm(p["ln1"], x, cfg.norm_type, cfg.norm_eps)
+        a, nk, nv = apply_attention(p["attn"], h, cfg, mode=mode, causal=True,
+                                    cache_k=ck, cache_v=cv, cache_index=ci,
+                                    positions=positions, impl=impl)
+        x = x + a
+    with jax.named_scope("attn"):
+        h = apply_norm(p["lnx"], x, cfg.norm_type, cfg.norm_eps)
+        if mode == "decode" and lc and "xk" in lc:
+            from repro.nn.attention import _gqa_scores_combine
+            dt = x.dtype
+            q = jnp.einsum("...d,dhk->...hk", h, p["xattn"]["wq"].astype(dt))
+            if "bq" in p["xattn"]:
+                q = q + p["xattn"]["bq"].astype(dt)
+            mask = jnp.zeros((1, 1, 1, lc["xk"].shape[1]), jnp.float32)
+            out = _gqa_scores_combine(q, lc["xk"].astype(dt),
+                                      lc["xv"].astype(dt), mask)
+            a = jnp.einsum("...hk,hkd->...d", out,
+                           p["xattn"]["wo"].astype(dt))
+            nxk, nxv = lc["xk"], lc["xv"]
+        else:
+            a, _, _ = apply_attention(p["xattn"], h, cfg, mode="train",
+                                      causal=False, kv_x=enc_out, impl=impl)
+            dt = x.dtype
+            nxk = jnp.einsum("...d,dhk->...hk", enc_out,
+                             p["xattn"]["wk"].astype(dt))
+            nxv = jnp.einsum("...d,dhk->...hk", enc_out,
+                             p["xattn"]["wv"].astype(dt))
+            if "bk" in p["xattn"]:
+                nxk = nxk + p["xattn"]["bk"].astype(dt)
+                nxv = nxv + p["xattn"]["bv"].astype(dt)
+        x = x + a
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps)
+        m = apply_mlp(p["mlp"], h, cfg.mlp_type)
+        x = x + m
     nlc = None
     if lc is not None:
         nlc = {"k": nk, "v": nv, "xk": nxk, "xv": nxv}

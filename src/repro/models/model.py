@@ -208,15 +208,20 @@ def _learned_pos(p, positions, max_len, dtype):
 
 
 def _head(params, cfg, x):
-    x = apply_norm(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)
-    if cfg.arch_type == "mlm":
-        t = params["mlm_transform"]
-        x = jnp.einsum("...d,de->...e", x, t["w"].astype(x.dtype)) + t["b"].astype(x.dtype)
-        x = jax.nn.gelu(x)
-        x = apply_norm(t["ln"], x, cfg.norm_type, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        return apply_lm_head(None, x, embedding_table=params["embed"]["table"])
-    return apply_lm_head(params["lm_head"], x)
+    """Final norm, the MLM transform and the vocabulary projection (named
+    scope ``lm_head`` on the device trace)."""
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)
+        if cfg.arch_type == "mlm":
+            t = params["mlm_transform"]
+            x = (jnp.einsum("...d,de->...e", x, t["w"].astype(x.dtype))
+                 + t["b"].astype(x.dtype))
+            x = jax.nn.gelu(x)
+            x = apply_norm(t["ln"], x, cfg.norm_type, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            return apply_lm_head(None, x,
+                                 embedding_table=params["embed"]["table"])
+        return apply_lm_head(params["lm_head"], x)
 
 
 def apply_model(params, cfg, batch: Dict[str, Any], *, mode: str = "train",
@@ -235,10 +240,12 @@ def apply_model(params, cfg, batch: Dict[str, Any], *, mode: str = "train",
     index = cache["index"] if cache is not None else jnp.zeros((), jnp.int32)
     positions = _positions(mode, Bn, S, index)
 
-    x = apply_embedding(params["embed"], tokens, dt)
-    x = constrain(x, (P.BATCH, P.SEQ, P.EMBED))
-    if "pos" in params and cfg.arch_type != "audio":
-        x = x + _learned_pos(params["pos"], positions, cfg.max_seq_len, dt)
+    with jax.named_scope("embed"):
+        x = apply_embedding(params["embed"], tokens, dt)
+        x = constrain(x, (P.BATCH, P.SEQ, P.EMBED))
+        if "pos" in params and cfg.arch_type != "audio":
+            x = x + _learned_pos(params["pos"], positions, cfg.max_seq_len,
+                                 dt)
 
     at = cfg.arch_type
     new_layers = None

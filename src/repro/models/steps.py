@@ -47,16 +47,17 @@ def lm_loss(logits: jax.Array, targets: jax.Array, loss_mask: jax.Array):
     ``take_along_axis``: gathering along a *model-sharded* vocab axis would
     make GSPMD all-gather the full (B,S,V) logits per device (hundreds of GB
     at train_4k scale); the masked reduction stays sharded and lowers to one
-    small all-reduce."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
-                                          logits.ndim - 1)
-    gold = jnp.sum(jnp.where(vocab_iota == targets[..., None], logits, 0.0),
-                   axis=-1)
-    nll = (logz - gold) * loss_mask
-    count = jnp.maximum(jnp.sum(loss_mask), 1.0)
-    return jnp.sum(nll) / count, count
+    small all-reduce.  Named scope ``loss`` on the device trace."""
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                              logits.ndim - 1)
+        gold = jnp.sum(jnp.where(vocab_iota == targets[..., None], logits,
+                                 0.0), axis=-1)
+        nll = (logz - gold) * loss_mask
+        count = jnp.maximum(jnp.sum(loss_mask), 1.0)
+        return jnp.sum(nll) / count, count
 
 
 def _objective(params, cfg, batch, frozen, impl):
@@ -169,15 +170,16 @@ def make_train_step(cfg, optimizer, *, frozen: Optional[Tuple[bool, ...]] = None
         else:
             grads, metrics = one_micro(params, anchor, batch)
 
-        if clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        else:
-            gnorm = jnp.zeros((), jnp.float32)
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        if frozen is not None and any(frozen):
-            updates, new_opt = _apply_freeze_to_updates(
-                cfg, frozen, updates, new_opt, opt_state)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            if clip_norm:
+                grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            else:
+                gnorm = jnp.zeros((), jnp.float32)
+            updates, new_opt = optimizer.update(grads, opt_state, params)
+            if frozen is not None and any(frozen):
+                updates, new_opt = _apply_freeze_to_updates(
+                    cfg, frozen, updates, new_opt, opt_state)
+            params = apply_updates(params, updates)
         metrics = dict(metrics, grad_norm=gnorm)
         return params, new_opt, metrics
 
@@ -207,37 +209,39 @@ def make_masked_train_step(cfg, optimizer, *, impl: str = "xla",
 
     def train_step(params, opt_state, anchor, batch, freeze_mask):
         (total, metrics), grads = grad_fn(params, anchor, batch)
-        keep = 1.0 - freeze_mask                       # (L,) traced
+        with jax.named_scope("optimizer"):
+            keep = 1.0 - freeze_mask                       # (L,) traced
 
-        def mask_stacked(path_grads):
-            def one(g):
-                shape = (-1,) + (1,) * (g.ndim - 1)
-                return g * keep.reshape(shape).astype(g.dtype)
-            return jax.tree.map(one, path_grads)
+            def mask_stacked(path_grads):
+                def one(g):
+                    shape = (-1,) + (1,) * (g.ndim - 1)
+                    return g * keep.reshape(shape).astype(g.dtype)
+                return jax.tree.map(one, path_grads)
 
-        grads = dict(grads)
-        grads["layers"] = mask_stacked(grads["layers"])
-        if clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        else:
-            gnorm = jnp.zeros((), jnp.float32)
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        # frozen layers fully untouched: zero updates + restore moments
-        updates = dict(updates)
-        updates["layers"] = mask_stacked(updates["layers"])
-        sel = freeze_mask > 0.5
+            grads = dict(grads)
+            grads["layers"] = mask_stacked(grads["layers"])
+            if clip_norm:
+                grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            else:
+                gnorm = jnp.zeros((), jnp.float32)
+            updates, new_opt = optimizer.update(grads, opt_state, params)
+            # frozen layers fully untouched: zero updates + restore moments
+            updates = dict(updates)
+            updates["layers"] = mask_stacked(updates["layers"])
+            sel = freeze_mask > 0.5
 
-        def restore(new, old):
-            s = sel.reshape((-1,) + (1,) * (new.ndim - 1))
-            return jnp.where(s, old, new)
+            def restore(new, old):
+                s = sel.reshape((-1,) + (1,) * (new.ndim - 1))
+                return jnp.where(s, old, new)
 
-        for field in ("m", "v"):
-            if field in new_opt:
-                new_opt = dict(new_opt)
-                new_opt[field] = dict(new_opt[field])
-                new_opt[field]["layers"] = jax.tree.map(
-                    restore, new_opt[field]["layers"], opt_state[field]["layers"])
-        params = apply_updates(params, updates)
+            for field in ("m", "v"):
+                if field in new_opt:
+                    new_opt = dict(new_opt)
+                    new_opt[field] = dict(new_opt[field])
+                    new_opt[field]["layers"] = jax.tree.map(
+                        restore, new_opt[field]["layers"],
+                        opt_state[field]["layers"])
+            params = apply_updates(params, updates)
         return params, new_opt, dict(metrics, grad_norm=gnorm)
 
     if prox_mu:

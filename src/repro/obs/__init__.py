@@ -3,10 +3,12 @@
 The measurement counterpart of the repo's three predictors (analytic
 telemetry, wall-clock simulator, calibrated presets):
 
-  * :mod:`repro.obs.trace`   — low-overhead span tracer (context-manager +
-    decorator API, monotonic clocks, thread-safe ring buffer, no-op when
-    disabled) with Chrome trace-event JSON export (Perfetto-loadable);
-    synthetic spans let the simulator replay onto the same timeline.
+  * :mod:`repro.obs.trace`   — low-overhead span tracer (context-manager
+    API, monotonic clocks, parent and round per span, thread-safe ring
+    buffer, no-op when disabled) with Chrome trace-event JSON export
+    (Perfetto-loadable); each span is mirrored as a ``jax.profiler``
+    annotation, and synthetic spans let the simulator replay onto the
+    same timeline.
   * :mod:`repro.obs.metrics` — process-wide registry of counters / gauges
     / histograms with exact, version-pinned quantiles and JSONL export.
   * :mod:`repro.obs.drift`   — per-round measured-vs-predicted ratio
@@ -17,8 +19,8 @@ telemetry, wall-clock simulator, calibrated presets):
 
 The process-wide tracer starts DISABLED: instrumented hot paths
 (``core/rounds.py``, ``serve/engine.py``, ``sim/events.py``) pay one
-attribute check until a driver opts in (``--trace-out`` or
-``repro.obs.enable()``).
+attribute check until an entry point opts in (``--trace-out``,
+``--jax-profile`` or ``repro.obs.enable()``).
 """
 
 from repro.obs.drift import (DriftMonitor, DriftRecord, from_history,
@@ -28,7 +30,7 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from repro.obs.profile import capture_compiles, jax_profile, record_compile
 from repro.obs.trace import (NULL_SPAN, PID_MEASURED, PID_SIM, SpanEvent,
                              Tracer, disable, enable, get_tracer, instant,
-                             span, traced)
+                             span)
 
 __all__ = [
     "Counter", "DriftMonitor", "DriftRecord", "Gauge", "Histogram",
@@ -36,5 +38,5 @@ __all__ = [
     "Tracer", "capture_compiles", "disable", "enable", "from_history",
     "get_tracer", "instant", "jax_profile", "load_jsonl",
     "measured_round_s", "predicted_round_s", "quantile", "record_compile",
-    "registry", "span", "summary_stats", "traced",
+    "registry", "span", "summary_stats",
 ]

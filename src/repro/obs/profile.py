@@ -6,15 +6,24 @@ Two independent capture layers on top of the span tracer:
     (TensorBoard/XProf format, device-level detail).  ``outdir=None`` is a
     no-op, so callers can wire it unconditionally; a profiler that cannot
     start raises, so a run asked for a device trace never ends without
-    one.
+    one.  While the span tracer is enabled, each of its spans enters a
+    ``TraceAnnotation`` of the same name, so the trace's host plane
+    carries the round engine's spans (``train.round``, ``train.prepare``,
+    ``train.dispatch`` with ``train.stack`` and ``train.launch``,
+    ``train.combine``, ``train.wait``, ``train.account``) on the device ops' own clock, and
+    the device ops carry the programs' named scopes (``embed``, ``attn``,
+    ``mlp``, ``lm_head``, ``loss``, ``optimizer``, ``fold``) in their
+    ``tf_op`` metadata (``launch.cache.use_compile_cache`` keys the
+    compile cache on that metadata, so a warm cache cannot serve a
+    program without it).
 
   * ``capture_compiles()`` — registers a ``jax.monitoring`` listener that
     turns every ``/jax/core/compile/*`` duration event (jaxpr trace, MLIR
     lowering, backend compile) into a span on the process-wide tracer
-    (category ``compile``) and bumps ``compile.events`` /
-    ``compile.total_s`` plus one ``compile.<phase>_s`` per phase
-    (``compile.backend_compile_s`` is the part a persistent-cache hit
-    saves) in the metrics registry.  Compile time is the #1
+    (category ``compile``) and bumps ``compile.events`` plus one
+    ``compile.<phase>_s`` per phase (``compile.backend_compile_s`` is the
+    part a persistent-cache hit saves) in the metrics registry.  Phases
+    nest, so their seconds are not summed.  Compile time is the #1
     confound in round-time drift — a retrace shows up as a fat span right
     where the round got slow instead of as an unexplained 30s ratio spike.
 
@@ -60,7 +69,6 @@ def _on_duration_event(event: str, duration_secs: float, **kw: Any) -> None:
         name = name[: -len("_duration")]
     secs = max(duration_secs, 0.0)
     _registry().counter("compile.events").inc()
-    _registry().counter("compile.total_s").inc(secs)
     _registry().counter(f"compile.{name}_s").inc(secs)
     tracer = get_tracer()
     if not tracer.enabled:

@@ -5,10 +5,15 @@ into a fixed-capacity thread-safe ring buffer, and exports them as Chrome
 trace-event JSON (the ``{"traceEvents": [...]}`` format Perfetto and
 ``chrome://tracing`` load directly).  Three kinds of event:
 
-  * measured spans — ``with tracer.span("train.dispatch", round=t): ...``
-    (or the ``@traced`` decorator).  Timestamps come from
-    ``time.perf_counter_ns`` (monotonic; immune to wall-clock steps) and
-    are exported relative to the tracer's epoch, one track per thread.
+  * measured spans — ``with tracer.span("train.dispatch", round=t): ...``.
+    Timestamps come from ``time.perf_counter_ns`` (monotonic; immune to
+    wall-clock steps) and are exported relative to the tracer's epoch, one
+    track per thread.  Each span records its parent (the innermost open
+    span of its thread) and its round (its own ``round`` argument, else
+    its parent's); both are exported in the event's ``args``.  A measured
+    span also enters a ``jax.profiler.TraceAnnotation`` of the same name,
+    so a ``jax.profiler`` trace taken meanwhile shows it on the host plane,
+    on the device trace's own clock.
   * instants — ``tracer.instant("train.compile")`` marks a point in time
     (trace-time events like a shard-program compile).
   * synthetic spans — ``tracer.add_span(name, ts_s=..., dur_s=...)``
@@ -21,7 +26,9 @@ Cost discipline: the module-level default tracer starts DISABLED, and a
 disabled tracer's ``span()`` returns one shared no-op singleton — no
 allocation, no clock read, one attribute check — so the round/decode hot
 paths can stay instrumented unconditionally.  Enabled, each span costs two
-monotonic clock reads and one locked ring-buffer append.
+monotonic clock reads, a push and pop on its thread's span stack, one
+profiler annotation (a no-op check while no profiler runs) and one locked
+ring-buffer append.
 
 The ring keeps the newest ``capacity`` events and counts what it dropped
 (``tracer.dropped``) — a long session degrades to "most recent window",
@@ -31,12 +38,11 @@ never to unbounded memory.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 # Chrome trace "pid" lanes: measured events vs synthetic (simulated) events
 # render as two named processes in one timeline.
@@ -48,7 +54,9 @@ PID_SIM = 2
 class SpanEvent:
     """One recorded event.  ``ts_us``/``dur_us`` are microseconds relative
     to the tracer's epoch; ``phase`` is the Chrome event phase ("X" =
-    complete span, "i" = instant)."""
+    complete span, "i" = instant).  ``parent`` names the measured span
+    this one opened inside (None at the top of its thread), and ``round``
+    is its round id, inherited from the parent when not given."""
 
     name: str
     cat: str
@@ -58,6 +66,8 @@ class SpanEvent:
     tid: int
     phase: str = "X"
     args: Optional[Dict[str, Any]] = None
+    parent: Optional[str] = None
+    round: Optional[int] = None
 
 
 class _NullSpan:
@@ -72,15 +82,21 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **args) -> None:
+        """Arguments known only inside the span; dropped while disabled."""
+
 
 NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live span handle (context manager).  Start/stop read
-    ``perf_counter_ns``; the finished event is appended on ``__exit__``."""
+    """A live span handle (context manager).  ``__enter__`` finds the
+    parent on its thread's span stack, reads ``perf_counter_ns`` and enters
+    the profiler annotation; ``__exit__`` undoes both and appends the
+    finished event."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_parent",
+                 "_round", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -89,19 +105,43 @@ class _Span:
         self._cat = cat
         self._args = args
         self._t0 = 0
+        self._parent: Optional["_Span"] = None
+        self._round = args.get("round") if args else None
+        self._ann = None
+
+    def set(self, **args) -> None:
+        """Add arguments known only inside the span (sizes of what it
+        made, say); they are exported with the others."""
+        self._args = {**(self._args or {}), **args}
 
     def __enter__(self) -> "_Span":
+        stack = self._tracer._stack()
+        if stack:
+            self._parent = stack[-1]
+            if self._round is None:
+                self._round = self._parent._round
+        stack.append(self)
+        # imported here: reading traces and metrics needs no jax
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(self._name)
         self._t0 = time.perf_counter_ns()
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(None, None, None)
         t1 = time.perf_counter_ns()
+        stack = self._tracer._stack()
+        if self in stack:
+            stack.remove(self)
         self._tracer._append(SpanEvent(
             name=self._name, cat=self._cat,
             ts_us=(self._t0 - self._tracer._epoch_ns) / 1e3,
             dur_us=(t1 - self._t0) / 1e3,
             pid=PID_MEASURED, tid=threading.get_ident() & 0xFFFF,
-            args=self._args))
+            args=self._args,
+            parent=self._parent._name if self._parent else None,
+            round=self._round))
         return False
 
 
@@ -122,6 +162,7 @@ class Tracer:
         self._buf: List[Optional[SpanEvent]] = [None] * capacity
         self._n = 0                     # total events ever appended
         self._epoch_ns = time.perf_counter_ns()
+        self._local = threading.local()  # .stack: the thread's open spans
 
     # -- recording ------------------------------------------------------
 
@@ -155,22 +196,11 @@ class Tracer:
             name=name, cat=cat, ts_us=ts_s * 1e6, dur_us=dur_s * 1e6,
             pid=pid, tid=tid, args=args or None))
 
-    def traced(self, name: Optional[str] = None, cat: str = ""):
-        """Decorator form: ``@tracer.traced("phase")``."""
-
-        def deco(fn: Callable) -> Callable:
-            label = name or fn.__qualname__
-
-            @functools.wraps(fn)
-            def wrapper(*a, **kw):
-                if not self.enabled:
-                    return fn(*a, **kw)
-                with self.span(label, cat=cat):
-                    return fn(*a, **kw)
-
-            return wrapper
-
-        return deco
+    def _stack(self) -> List["_Span"]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
     def _append(self, ev: SpanEvent) -> None:
         with self._lock:
@@ -221,8 +251,13 @@ class Tracer:
                 d["dur"] = e.dur_us
             if e.phase == "i":
                 d["s"] = "t"          # instant scope: thread
-            if e.args:
-                d["args"] = e.args
+            args = dict(e.args or {})
+            if e.parent is not None:
+                args["parent"] = e.parent
+            if e.round is not None:
+                args["round"] = e.round
+            if args:
+                d["args"] = args
             events.append(d)
         return {"traceEvents": events, "displayTimeUnit": "ms",
                 "otherData": {"dropped_events": self.dropped}}
@@ -279,21 +314,3 @@ def instant(name: str, cat: str = "", **args) -> None:
     if _TRACER.enabled:
         _TRACER.instant(name, cat=cat, **args)
 
-
-def traced(name: Optional[str] = None, cat: str = ""):
-    """Decorator on the process-wide tracer (resolves ``enabled`` at CALL
-    time, so decorating at import cost nothing until someone enables)."""
-
-    def deco(fn: Callable) -> Callable:
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not _TRACER.enabled:
-                return fn(*a, **kw)
-            with _TRACER.span(label, cat=cat):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco

@@ -45,12 +45,13 @@ def make_peft_train_step(cfg, optimizer, space: ParamSpace, *,
 
     def train_step(bank, opt_state, base, anchor, batch):
         (_, metrics), grads = grad_fn(bank, base, anchor, batch)
-        if clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        else:
-            gnorm = jnp.zeros((), jnp.float32)
-        updates, new_opt = optimizer.update(grads, opt_state, bank)
-        bank = apply_updates(bank, updates)
+        with jax.named_scope("optimizer"):
+            if clip_norm:
+                grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            else:
+                gnorm = jnp.zeros((), jnp.float32)
+            updates, new_opt = optimizer.update(grads, opt_state, bank)
+            bank = apply_updates(bank, updates)
         return bank, new_opt, dict(metrics, grad_norm=gnorm)
 
     if prox_mu:

@@ -5,6 +5,7 @@ drift monitor, sim-span parity with ``ClientTiming`` totals, and the
 end-to-end join over a tiny traced ``FedSession``."""
 
 import json
+import re
 import threading
 import time
 
@@ -110,21 +111,122 @@ def test_chrome_trace_schema_roundtrip(tmp_path):
 
 
 def test_traced_decorator_and_thread_tracks():
+    """Spans from several threads land on one track per thread, and each
+    thread's span stack is its own: a span opened in a worker never takes
+    another thread's open span as its parent."""
     tr = obs.enable(capacity=128)
 
-    @obs.traced("worker", cat="t")
     def work():
-        time.sleep(0.001)
+        with obs.span("worker", cat="t"):
+            time.sleep(0.001)
 
     threads = [threading.Thread(target=work) for _ in range(3)]
     for t in threads:
         t.start()
     for t in threads:
         t.join()
-    work()
+    with obs.span("main", cat="t", round=7):
+        work()
     evs = [e for e in tr.events() if e.name == "worker"]
     assert len(evs) == 4
     assert len({e.tid for e in evs}) >= 2   # one track per thread
+    # only the main thread's worker span opened inside "main"
+    assert sorted((e.parent, e.round) for e in evs
+                  if e.parent is not None) == [("main", 7)]
+    assert sum(e.parent is None for e in evs) == 3
+
+
+def test_span_records_parent_and_round():
+    tr = Tracer(capacity=16)
+    with tr.span("train.round", round=4):
+        with tr.span("train.dispatch", shard=0):
+            with tr.span("train.stack") as sp:
+                sp.set(bytes=12)
+        with tr.span("train.wait", round=5):
+            pass
+    with tr.span("train.account"):
+        pass
+    got = {e.name: (e.parent, e.round) for e in tr.events()}
+    assert got == {"train.round": (None, 4),
+                   "train.dispatch": ("train.round", 4),
+                   "train.stack": ("train.dispatch", 4),
+                   "train.wait": ("train.round", 5),
+                   "train.account": (None, None)}
+    exported = {e["name"]: e.get("args") for e in
+                tr.chrome_trace()["traceEvents"] if e["ph"] == "X"}
+    assert exported["train.stack"] == {"bytes": 12,
+                                       "parent": "train.dispatch",
+                                       "round": 4}
+    assert exported["train.account"] is None
+    assert NULL_SPAN.set(bytes=1) is None       # disabled: dropped
+
+
+def test_spans_mirror_into_profiler_trace(tmp_path):
+    """Each enabled span enters a profiler annotation of its name: on a CPU
+    profile the annotation starts within 50 us of where the benchmark's
+    origin rule (a clock reading taken as the window annotation opens)
+    puts the ring's span."""
+    import glob
+    from jax.profiler import ProfileData
+    tr = obs.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    window = jax.profiler.TraceAnnotation("bench.window")
+    origin_ns = time.perf_counter_ns()
+    window.__enter__()
+    for r in range(3):
+        with obs.span("t.outer", round=r):
+            time.sleep(0.002)
+            with obs.span("t.inner"):
+                time.sleep(0.001)
+    window.__exit__(None, None, None)
+    jax.profiler.stop_trace()
+    obs.disable()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    host.setdefault(ev.name, []).append(ev.start_ns)
+    (win_ns,) = host["bench.window"]
+    ring = [e for e in tr.events() if e.name.startswith("t.")]
+    assert len(ring) == 6
+    for e in ring:
+        placed = tr._epoch_ns + e.ts_us * 1e3 - origin_ns + win_ns
+        assert len(host[e.name]) == 3
+        assert min(abs(placed - h) for h in host[e.name]) < 50_000
+
+
+def test_compile_cache_keys_on_op_metadata(monkeypatch, tmp_path):
+    """The persistent cache's keys leave op metadata out unless told: a
+    program is never served from an entry compiled without its named
+    scopes.  Source paths in that metadata are relative to the checkout,
+    so a tree keys alike wherever it is unpacked; tracing leaves the
+    keying alone."""
+    from repro.launch import cache
+
+    keys = ("jax_compilation_cache_include_metadata_in_key",
+            "jax_hlo_source_file_canonicalization_regex")
+    before = {k: getattr(jax.config, k) for k in keys}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        assert cache.use_compile_cache() == str(tmp_path)
+        assert getattr(jax.config, keys[0]) is True
+        obs.enable()
+        obs.disable()
+        assert getattr(jax.config, keys[0]) is True
+
+        def scoped(x):
+            with jax.named_scope("probe"):
+                return x * 2.0
+
+        text = jax.jit(scoped).lower(1.0).as_text(debug_info=True)
+        assert "probe" in text
+        assert "tests/test_obs.py" in text
+        assert str(cache.ROOT) + "/" not in text
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
 
 
 def test_enable_resets_and_keeps_identity():
@@ -199,7 +301,8 @@ def test_compile_listener_counts_compile_phases_only():
     _on_duration_event("/jax/compilation_cache/cache_retrieval_time_sec", 0.1)
     reg = obs.registry()
     assert reg.counter("compile.events").value == 2
-    assert reg.counter("compile.total_s").value == 1.75
+    # phases nest, so no counter sums them
+    assert "compile.total_s" not in reg.snapshot()
     assert reg.counter("compile.backend_compile_s").value == 1.5
     assert reg.counter("compile.jaxpr_trace_s").value == 0.25
 
@@ -373,15 +476,97 @@ def traced_session():
 
 def test_session_emits_expected_spans(traced_session):
     names = {e.name for e in traced_session["events"]}
-    assert {"train.round", "train.dispatch",
-            "train.aggregate"} <= names
+    assert {"train.round", "train.prepare", "train.dispatch", "train.stack",
+            "train.launch", "train.combine", "train.wait",
+            "train.account"} <= names
+    assert "train.aggregate" not in names
     rounds = [e for e in traced_session["events"]
               if e.name == "train.round"]
     assert [e.args["round"] for e in rounds] == [0, 1]
+    parents = {e.name: e.parent for e in traced_session["events"]
+               if e.name.startswith("train.")}
+    assert parents == {"train.round": None, "train.prepare": "train.round",
+                       "train.dispatch": "train.round",
+                       "train.stack": "train.dispatch",
+                       "train.launch": "train.dispatch",
+                       "train.combine": "train.round",
+                       "train.wait": "train.round", "train.account": None}
+    # children carry their round without being told it
+    stacks = [e for e in traced_session["events"] if e.name == "train.stack"]
+    assert sorted({e.round for e in stacks}) == [0, 1]
+    assert all(e.args["arrays"] > 0 and e.args["bytes"] > 0 for e in stacks)
     reg = traced_session["reg"]
     assert reg["train.rounds"]["value"] == 2
     assert reg["train.round_s"]["count"] == 2
     assert reg["train.tokens"]["value"] > 0
+
+
+SCOPES = ("embed", "attn", "mlp", "lm_head", "loss", "optimizer", "fold")
+
+
+@pytest.fixture(scope="module")
+def round_programs():
+    """One traced round of the cohort-scan engine at a small width, and the
+    compiled text of its shard and combine programs.  FedAvgM, so the
+    combine computes (FedAvg's only copies the mean out)."""
+    from repro import optim
+    from repro.configs import get_config
+    from repro.core.noniid import make_client_datasets
+    from repro.core.rounds import FedSession, RoundPlan
+    from repro.core.strategy import FedAvgM
+    from repro.data.corpus import generate_corpus
+    from repro.models.model import init_model
+    from repro.nn import param as P
+
+    cfg = get_config("distilbert-mlm").reduced()
+    params0 = P.unbox(init_model(jax.random.PRNGKey(0), cfg))
+    ds = make_client_datasets(generate_corpus(30, seed=1), cfg, k=2,
+                              skew="iid", batch=2, seq=16)
+    batches = [b[:1] for b in ds["batches"]]
+    tr = obs.enable()
+    try:
+        sess = FedSession(cfg, optim.adam(1e-3),
+                          RoundPlan(n_rounds=1, engine="parallel",
+                                    strategy=FedAvgM(), telemetry=False))
+        sess.run(params0, batches)
+        events = tr.events()
+    finally:
+        obs.disable()
+    strategy = sess.plan.strategy
+    shard = sess.shard_program.lower(*sess.shard_args).compile().as_text()
+    combine = sess.combine_program.lower(
+        params0, strategy.aggregate_init(params0),
+        strategy.init_state(params0)).compile().as_text()
+    return {"shard": shard, "combine": combine, "events": events}
+
+
+def _has_scope(hlo_text: str, scope: str) -> bool:
+    return re.search(r'op_name="[^"]*[/(]%s[/)][^"]*"' % scope,
+                     hlo_text) is not None
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_named_scopes_in_round_programs(round_programs, scope):
+    """Named scopes change only op metadata: each one reaches the compiled
+    per-round programs (``fold`` both of them), whose jit names the device
+    trace's module names come from."""
+    assert "HloModule jit__fed_shard" in round_programs["shard"]
+    assert "HloModule jit__fed_combine" in round_programs["combine"]
+    assert _has_scope(round_programs["shard"], scope)
+    assert _has_scope(round_programs["combine"], scope) == (scope == "fold")
+
+
+def test_parallel_engine_span_tree(round_programs):
+    got = {e.name: (e.parent, e.round) for e in round_programs["events"]
+           if e.name.startswith("train.")}
+    assert got == {"train.round": (None, 0),
+                   "train.prepare": ("train.round", 0),
+                   "train.dispatch": ("train.round", 0),
+                   "train.stack": ("train.dispatch", 0),
+                   "train.launch": ("train.dispatch", 0),
+                   "train.combine": ("train.round", 0),
+                   "train.wait": ("train.round", 0),
+                   "train.account": (None, 0)}
 
 
 def test_session_drift_ratios_within_tolerance(traced_session):

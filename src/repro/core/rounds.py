@@ -72,6 +72,7 @@ each round's local training; 1 local epoch per round; 15 rounds.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -84,7 +85,7 @@ from repro.core import ffdapt as ffd
 from repro.core.accounting import split_bytes
 from repro.core.fedavg import broadcast_clients, fedavg_stacked, scalar_fold
 from repro.core.strategy import FedAvg, FederatedStrategy
-from repro.models.steps import make_masked_train_step
+from repro.models.steps import head_capacity, make_masked_train_step
 from repro.nn import param as P
 from repro.peft.space import ParamSpace, frozen_shippable_template
 from repro.obs.metrics import registry as _obs_registry
@@ -317,6 +318,28 @@ def _footprint(tree) -> Dict[str, int]:
     leaves = jax.tree.leaves(tree)
     return {"arrays": len(leaves),
             "bytes": sum(int(x.nbytes) for x in leaves)}
+
+
+def _head_capacity(cfg, data, part: Sequence[int]) -> Optional[int]:
+    """The round's LM-head capacity (``models.steps.head_capacity``) over
+    the largest loss-mask count among the participants' batches, chosen
+    once per round before sharding so every shard runs one program.
+    Records the gauges ``train.head_capacity`` (rows the head runs at) and
+    ``train.head_fill`` (largest count over them), and counts a round that
+    runs the head at every position in ``train.head_full``."""
+    most = positions = 0
+    for k in part:
+        for b in data.batches_for(k):
+            mask = np.asarray(b["loss_mask"])
+            most = max(most, int(np.count_nonzero(mask)))
+            positions = mask.size
+    cap = head_capacity(most, positions, cfg.mlm_mask_rate)
+    rows = positions if cap is None else cap
+    reg = _obs_registry()
+    reg.gauge("train.head_capacity").set(rows)
+    reg.gauge("train.head_fill").set(most / rows)
+    reg.counter("train.head_full").inc(int(cap is None))
+    return cap
 
 
 def _record_round_metrics(rr: "RoundResult") -> None:
@@ -587,7 +610,7 @@ class FedSession:
     # Sequential (paper-faithful; static FFDAPT windows)
     # -----------------------------------------------------------------
 
-    def _step_for(self, frozen):
+    def _step_for(self, frozen, cap=None):
         # Keyed on the strategy's CLIENT-STEP identity, not the strategy
         # itself: FedAvg/FedAvgM/Compressed share one compiled program,
         # FedProx compiles per distinct mu.  Keys hold strong refs to
@@ -600,7 +623,7 @@ class FedSession:
         space = getattr(self, "_space", None)
         skey = space.step_key(frozen) if space is not None else frozen
         key = (self.cfg, self.optimizer, self.plan.strategy.client_step_key(),
-               skey, self.plan.impl)
+               skey, self.plan.impl, cap)
         if key not in _STEP_CACHE:
             # a cache miss means the next call traces+compiles a new client
             # program — mark it so the trace shows which round paid it
@@ -612,7 +635,7 @@ class FedSession:
                 kw["space"] = space
             _STEP_CACHE[key] = jax.jit(self.plan.strategy.make_client_step(
                 self.cfg, self.optimizer, frozen=frozen, impl=self.plan.impl,
-                **kw))
+                head_capacity=cap, **kw))
         return _STEP_CACHE[key]
 
     def _client_upload_bytes(self, params, part, windows, n_units, t):
@@ -677,6 +700,7 @@ class FedSession:
                 with _obs_span("train.prepare", cat="train"):
                     part = _participants(rng, len(data), plan.participation)
                     down = strategy.download_bytes(params, len(part))
+                    cap = _head_capacity(self.cfg, data, part)
                 locals_, losses, tokens = [], [], 0.0
                 flops_e = hbm_e = coll_e = 0.0
                 c_steps, c_flops, c_hbm = [], [], []
@@ -712,8 +736,8 @@ class FedSession:
                         with _obs_span("train.launch", cat="train",
                                        steps=steps_k):
                             p_k, _, loss, tok = _epoch(
-                                self._step_for(frozen), params, opt_state,
-                                bs_k, *extra)
+                                self._step_for(frozen, cap), params,
+                                opt_state, bs_k, *extra)
                     locals_.append(p_k)
                     losses.append(loss)
                     tokens += tok
@@ -790,27 +814,27 @@ class FedSession:
 
         use_mask = windows is not None
         step_kw = {"space": space} if peft else {}
-        client_step = strategy.make_client_step(
-            self.cfg, optimizer, masked=use_mask, impl=plan.impl, **step_kw)
         needs_anchor = strategy.needs_anchor
 
         # traced (= compiled) shard-program count this session: the
         # compile-count invariant tests/test_cohort.py pins — one program
         # per distinct shard WIDTH (so 1, or 2 when the shard size does
-        # not divide the cohort), never one per shard or per round.
+        # not divide the cohort), never one per shard or per round, while
+        # the rounds keep one LM-head capacity.
         self.shard_compiles = 0
 
-        def _fed_shard(global_params, base_params, partial, loss_acc,
-                       tok_acc, bsub, fmasks, w_agg, w_loss):
+        def _fed_shard(client_step, global_params, base_params, partial,
+                       loss_acc, tok_acc, bsub, fmasks, w_agg, w_loss):
             """One cohort shard: vmapped local epochs + streaming fold.
 
-            ``global_params`` is the aggregated tree (the BANK under a
-            low-rank space, with ``base_params`` the frozen base — None,
-            an empty pytree, otherwise); ``partial``/``loss_acc``/
-            ``tok_acc`` are the round's carries; ``w_agg`` is this shard's
-            slice of the cohort-normalized aggregation weights, ``w_loss``
-            the raw-normalized loss weights.  Traced once per shard width
-            (jit caches on shapes).
+            ``client_step`` is bound once per LM-head capacity
+            (``_shard_program``); ``global_params`` is the aggregated tree
+            (the BANK under a low-rank space, with ``base_params`` the
+            frozen base — None, an empty pytree, otherwise); ``partial``/
+            ``loss_acc``/``tok_acc`` are the round's carries; ``w_agg`` is
+            this shard's slice of the cohort-normalized aggregation
+            weights, ``w_loss`` the raw-normalized loss weights.  Traced
+            once per shard width and capacity (jit caches on shapes).
             """
             self.shard_compiles += 1          # trace-time, not per call
             ksub = fmasks.shape[0]
@@ -845,11 +869,25 @@ class FedSession:
             return (partial, scalar_fold(loss_acc, losses * w_loss),
                     scalar_fold(tok_acc, toks))
 
-        fed_shard = jax.jit(_fed_shard)
+        shard_programs: Dict[Optional[int], Callable] = {}
+
+        def _shard_program(cap):
+            # one jit per LM-head capacity; a round's shards share one
+            if cap not in shard_programs:
+                program = functools.partial(
+                    _fed_shard, strategy.make_client_step(
+                        self.cfg, optimizer, masked=use_mask, impl=plan.impl,
+                        head_capacity=cap, **step_kw))
+                # jit names the module (and the device trace's ops) from it:
+                # jit__fed_shard
+                program.__name__ = _fed_shard.__name__
+                shard_programs[cap] = jax.jit(program)
+            return shard_programs[cap]
+
         # the per-shard program and the abstract arguments of its first
         # call: ``shard_program.lower(*shard_args).compile()`` is the
         # program the rounds run, for checks of its text and memory
-        self.shard_program, self.shard_args = fed_shard, None
+        self.shard_program, self.shard_args = None, None
 
         @jax.jit
         def norm_weights(w):
@@ -901,6 +939,8 @@ class FedSession:
                     partial = strategy.aggregate_init(params)
                     loss_acc = jnp.zeros((), jnp.float32)
                     tok_acc = jnp.zeros((), jnp.float32)
+                    fed_shard = _shard_program(
+                        _head_capacity(self.cfg, data, part))
                 off = 0
                 for si, width in enumerate(_shard_widths(m,
                                                          plan.cohort_shard)):
@@ -927,6 +967,7 @@ class FedSession:
                                 bsub, fmasks, w_agg[off:off + width],
                                 w_loss[off:off + width])
                         if self.shard_args is None:
+                            self.shard_program = fed_shard
                             self.shard_args = jax.tree.map(
                                 lambda x: jax.ShapeDtypeStruct(x.shape,
                                                                x.dtype), args)

@@ -163,20 +163,24 @@ class FederatedStrategy:
     # -- client objective ---------------------------------------------
     def make_client_step(self, cfg, optimizer, *, frozen=None,
                          masked: bool = False, impl: str = "xla",
-                         space=None):
+                         space=None, head_capacity=None):
         """Local train step.  ``masked=False`` (sequential engine): static
         FFDAPT ``frozen`` window, signature ``step(params, opt, batch)`` —
         or ``step(params, opt, anchor, batch)`` when ``needs_anchor``.
         ``masked=True`` (mesh engine): traced freeze mask appended.
         A low-rank ``space`` (repro.peft) swaps in the PEFT step: ``params``
         becomes the factor bank and the frozen base model splices in as
-        ``step(bank, opt, base, [anchor,] batch)``."""
+        ``step(bank, opt, base, [anchor,] batch)``.  ``head_capacity``:
+        the LM head's rows (``models.steps.head_capacity``)."""
         if space is not None and space.low_rank:
             from repro.peft.step import make_peft_train_step
-            return make_peft_train_step(cfg, optimizer, space, impl=impl)
+            return make_peft_train_step(cfg, optimizer, space, impl=impl,
+                                        head_capacity=head_capacity)
         if masked:
-            return make_masked_train_step(cfg, optimizer, impl=impl)
-        return make_train_step(cfg, optimizer, frozen=frozen, impl=impl)
+            return make_masked_train_step(cfg, optimizer, impl=impl,
+                                          head_capacity=head_capacity)
+        return make_train_step(cfg, optimizer, frozen=frozen, impl=impl,
+                               head_capacity=head_capacity)
 
     def client_step_key(self) -> Tuple:
         """Cache identity of ``make_client_step``'s program: every strategy
@@ -333,18 +337,20 @@ class FedProx(FederatedStrategy):
 
     def make_client_step(self, cfg, optimizer, *, frozen=None,
                          masked: bool = False, impl: str = "xla",
-                         space=None):
+                         space=None, head_capacity=None):
         if space is not None and space.low_rank:
             # proximal pull toward the round-global BANK: base coordinates
             # never move, so ||bank - anchor||^2 is the whole prox term
             from repro.peft.step import make_peft_train_step
             return make_peft_train_step(cfg, optimizer, space, impl=impl,
-                                        prox_mu=self.mu)
+                                        prox_mu=self.mu,
+                                        head_capacity=head_capacity)
         if masked:
             return make_masked_train_step(cfg, optimizer, impl=impl,
-                                          prox_mu=self.mu)
+                                          prox_mu=self.mu,
+                                          head_capacity=head_capacity)
         return make_train_step(cfg, optimizer, frozen=frozen, impl=impl,
-                               prox_mu=self.mu)
+                               prox_mu=self.mu, head_capacity=head_capacity)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -207,10 +207,14 @@ def _learned_pos(p, positions, max_len, dtype):
     return apply_positional(p, pos, dtype)
 
 
-def _head(params, cfg, x):
+def _head(params, cfg, x, rows=None):
     """Final norm, the MLM transform and the vocabulary projection (named
-    scope ``lm_head`` on the device trace)."""
+    scope ``lm_head`` on the device trace).  ``rows`` (flat position
+    indices over batch x sequence) gathers those positions first, so the
+    head and its backward run at them alone; logits are then (rows, V)."""
     with jax.named_scope("lm_head"):
+        if rows is not None:
+            x = x.reshape(-1, x.shape[-1])[rows]
         x = apply_norm(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)
         if cfg.arch_type == "mlm":
             t = params["mlm_transform"]
@@ -226,13 +230,17 @@ def _head(params, cfg, x):
 
 def apply_model(params, cfg, batch: Dict[str, Any], *, mode: str = "train",
                 cache: Any = None, frozen: Optional[Tuple[bool, ...]] = None,
-                impl: str = "xla", last_only: bool = False):
+                impl: str = "xla", last_only: bool = False,
+                head_rows: Optional[jax.Array] = None):
     """batch: {"tokens": (B,S) int32, ["image_embeds"], ["frames"]}.
 
     Returns (logits (B,S,V), new_cache (or None), aux_loss scalar).
     mode: "train" (no cache) | "prefill" (fills cache) | "decode" (S==1).
     last_only: apply the LM head to the final position only (prefill) —
     the (B,S,vocab) buffer is the single largest activation at scale.
+    head_rows: (K,) flat indices into the B*S positions; the LM head runs
+    at those positions only and logits are (K, V) (training: the positions
+    the loss reads).
     """
     tokens = batch["tokens"]
     Bn, S = tokens.shape
@@ -302,7 +310,7 @@ def apply_model(params, cfg, batch: Dict[str, Any], *, mode: str = "train",
 
     if last_only:
         x = x[:, -1:, :]
-    logits = _head(params, cfg, x)
+    logits = _head(params, cfg, x, head_rows)
 
     new_cache = None
     if cache is not None:

@@ -13,6 +13,7 @@ applied by the caller (``repro.launch``) via in/out shardings.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -60,11 +61,45 @@ def lm_loss(logits: jax.Array, targets: jax.Array, loss_mask: jax.Array):
         return jnp.sum(nll) / count, count
 
 
-def _objective(params, cfg, batch, frozen, impl):
-    logits, _, aux = apply_model(params, cfg, batch, mode="train",
-                                 frozen=frozen, impl=impl)
-    loss, count = lm_loss(logits, batch["targets"],
-                          batch["loss_mask"].astype(jnp.float32))
+def head_capacity(max_count: int, positions: int,
+                  mask_rate: float) -> Optional[int]:
+    """Rows the vocabulary head runs at in a train step: the smallest rung
+    of ``K0, 2 K0, 4 K0, ...`` that holds ``max_count`` (the most
+    loss-masked positions of any batch the step will see), where ``K0`` is
+    1.25 x ``mask_rate`` x ``positions`` rounded up to a multiple of 128.
+    None (the head at every position) once the rung reaches ``positions``,
+    as it does for all-ones (CLM) masks."""
+    rung = 128 * max(1, math.ceil(round(1.25 * mask_rate * positions, 6)
+                                  / 128))
+    while rung < max_count:
+        rung *= 2
+    return rung if rung < positions else None
+
+
+def _objective(params, cfg, batch, frozen, impl, capacity=None):
+    """``capacity`` (static): run the LM head and the loss at that many
+    positions, the loss-masked ones first; it must be at least the
+    batch's masked count, so no position the loss reads is dropped.  The
+    other positions' logits carry no loss and no gradient, so the loss is
+    the same mean and the gradients the same up to f32 summation order.
+    None, or a capacity of every position, runs the head everywhere."""
+    mask = batch["loss_mask"]
+    if capacity is None or capacity >= mask.size:
+        logits, _, aux = apply_model(params, cfg, batch, mode="train",
+                                     frozen=frozen, impl=impl)
+        loss, count = lm_loss(logits, batch["targets"],
+                              mask.astype(jnp.float32))
+    else:
+        with jax.named_scope("lm_head"):
+            flat = mask.reshape(-1).astype(jnp.float32)
+            # masked positions first, in order; the rows past the batch's
+            # count are unmasked positions, which carry mask 0
+            rows = jnp.argsort(flat == 0, stable=True)[:capacity]
+        logits, _, aux = apply_model(params, cfg, batch, mode="train",
+                                     frozen=frozen, impl=impl,
+                                     head_rows=rows)
+        loss, count = lm_loss(logits, batch["targets"].reshape(-1)[rows],
+                              flat[rows])
     total = loss + cfg.router_aux_coef * aux
     return total, {"loss": loss, "aux": aux, "tokens": count}
 
@@ -123,7 +158,8 @@ def _apply_freeze_to_updates(cfg, frozen, updates, new_opt, old_opt):
 
 def make_train_step(cfg, optimizer, *, frozen: Optional[Tuple[bool, ...]] = None,
                     microbatches: int = 1, impl: str = "xla",
-                    clip_norm: float = 1.0, prox_mu: float = 0.0):
+                    clip_norm: float = 1.0, prox_mu: float = 0.0,
+                    head_capacity: Optional[int] = None):
     """-> train_step(params, opt_state, batch) -> (params, opt_state, metrics).
 
     ``frozen``: static per-freeze-unit mask (FFDAPT); recompiled per distinct
@@ -131,9 +167,12 @@ def make_train_step(cfg, optimizer, *, frozen: Optional[Tuple[bool, ...]] = None
     ``prox_mu`` > 0 adds FedProx's mu/2 ||w - w_global||^2 to the objective
     and changes the signature to ``step(params, opt_state, anchor, batch)``
     (the global anchor changes every round, so it is a per-call argument).
+    ``head_capacity``: the rows the LM head runs at, as the module's
+    ``head_capacity`` ladder picks them; None runs it at every position.
     """
     def objective(params, anchor, batch):
-        total, metrics = _objective(params, cfg, batch, frozen, impl)
+        total, metrics = _objective(params, cfg, batch, frozen, impl,
+                                    head_capacity)
         if prox_mu:
             prox = prox_mu * proximal_penalty(params, anchor)
             total = total + prox
@@ -190,15 +229,18 @@ def make_train_step(cfg, optimizer, *, frozen: Optional[Tuple[bool, ...]] = None
 
 
 def make_masked_train_step(cfg, optimizer, *, impl: str = "xla",
-                           clip_norm: float = 1.0, prox_mu: float = 0.0):
+                           clip_norm: float = 1.0, prox_mu: float = 0.0,
+                           head_capacity: Optional[int] = None):
     """Single-program FFDAPT variant: ``freeze_mask`` is a TRACED (L,) float
     {0,1} array multiplying the main-stack gradients — one compiled program
     serves every round, but backward FLOPs are NOT saved (only updates are
     suppressed).  Supported for uniform-stack archs (``layers`` leading dim).
     ``prox_mu`` > 0 adds the FedProx term and the signature becomes
-    ``step(params, opt_state, anchor, batch, freeze_mask)``."""
+    ``step(params, opt_state, anchor, batch, freeze_mask)``.
+    ``head_capacity`` as in ``make_train_step``."""
     def objective(params, anchor, batch):
-        total, metrics = _objective(params, cfg, batch, None, impl)
+        total, metrics = _objective(params, cfg, batch, None, impl,
+                                    head_capacity)
         if prox_mu:
             prox = prox_mu * proximal_penalty(params, anchor)
             total = total + prox

@@ -27,14 +27,14 @@ from repro.peft.space import ParamSpace
 
 def make_peft_train_step(cfg, optimizer, space: ParamSpace, *,
                          impl: str = "xla", clip_norm: float = 1.0,
-                         prox_mu: float = 0.0):
+                         prox_mu: float = 0.0, head_capacity=None):
     if not space.low_rank:
         raise ValueError(f"make_peft_train_step needs a low-rank space, "
                          f"got {space.kind!r}")
 
     def objective(bank, base, anchor, batch):
         total, metrics = _objective(space.merge(base, bank), cfg, batch,
-                                    None, impl)
+                                    None, impl, head_capacity)
         if prox_mu:
             prox = prox_mu * proximal_penalty(bank, anchor)
             total = total + prox

@@ -128,6 +128,60 @@ def test_cohort_scan_participation_parity(params0, clients):
     _assert_bitwise(p_full, p_scan)
 
 
+@pytest.fixture(scope="module")
+def wide_clients():
+    """4 clients x 2 steps of 4 x 128: B*S = 512 positions, so the LM head
+    runs gathered at the ladder's floor of 128 rows (15% masks, about 77
+    masked positions a batch)."""
+    ds = make_client_datasets(DOCS, CFG, k=4, skew="iid", batch=4, seq=128)
+    return [b[:2] for b in ds["batches"]], ds["sizes"]
+
+
+def test_cohort_scan_gathered_head_parity(params0, wide_clients):
+    """With the head at the masked positions, every shard schedule stays
+    bitwise the full-width round, each width still compiles one program,
+    and the round's capacity (128 < 512) is what the registry reads."""
+    from repro import obs
+    batches, sizes = wide_clients
+    kw = dict(n_rounds=2, seed=3)
+    p_full, h_full, s_full = _run(params0, batches, sizes, shard=None, **kw)
+    p_scan, h_scan, s_scan = _run(params0, batches, sizes, shard=2, **kw)
+    _assert_bitwise(p_full, p_scan)
+    assert [h.loss for h in h_full] == [h.loss for h in h_scan]
+    assert s_full.shard_compiles == 1             # widths [4]
+    assert s_scan.shard_compiles == 1             # widths [2, 2]
+    assert obs.registry().gauge("train.head_capacity").value == 128
+    # the shard program holds the gathered head: no (B, S, vocab) logits
+    text = s_scan.shard_program.lower(*s_scan.shard_args).as_text()
+    assert f"4x128x{CFG.vocab_size}x" not in text
+    assert f"128x{CFG.vocab_size}x" in text
+
+
+@pytest.mark.parametrize("engine", ["parallel", "sequential"])
+@pytest.mark.parametrize("masks", ["mlm", "all_ones"])
+def test_head_counters_after_a_session(params0, wide_clients, engine, masks):
+    """``train.head_capacity``, ``train.head_fill`` and ``train.head_full``
+    after a short run: MLM masks run gathered at 128 rows; all-ones masks
+    (a CLM objective's) run the head at every position, once per round."""
+    from repro import obs
+    batches, sizes = wide_clients
+    if masks == "all_ones":
+        batches = [[dict(b, loss_mask=np.ones_like(b["loss_mask"]))
+                    for b in bs] for bs in batches]
+    most = max(int(np.count_nonzero(b["loss_mask"]))
+               for bs in batches for b in bs)
+    reg = obs.registry()
+    full0 = reg.counter("train.head_full").value
+    plan = RoundPlan(client_sizes=sizes, engine=engine, cohort_shard=2,
+                     telemetry=False, n_rounds=2, seed=0)
+    FedSession(CFG, OPT, plan).run(params0, batches)
+    rows = 128 if masks == "mlm" else 512
+    assert reg.gauge("train.head_capacity").value == rows
+    assert reg.gauge("train.head_fill").value == most / rows
+    assert reg.counter("train.head_full").value - full0 == (
+        0 if masks == "mlm" else 2)
+
+
 # ----------------------------------------------------------- compile count
 
 def test_compile_count_independent_of_cohort(params0, clients):

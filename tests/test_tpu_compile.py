@@ -101,7 +101,7 @@ def test_kernel_compiles_to_mosaic(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _distilbert_step(impl):
+def _distilbert_step(impl, head_capacity=None):
     from repro import optim
     from repro.configs import get_config
     from repro.core.strategy import make_strategy
@@ -109,7 +109,8 @@ def _distilbert_step(impl):
     from repro.telemetry.step import train_batch_struct
     cfg = get_config("distilbert-mlm")
     opt = optim.adam(5e-5)
-    step = make_strategy("fedavg").make_client_step(cfg, opt, impl=impl)
+    step = make_strategy("fedavg").make_client_step(
+        cfg, opt, impl=impl, head_capacity=head_capacity)
     params, opt_state = abstract_train_state(cfg, opt)
     return step, (params, opt_state, train_batch_struct(cfg, 32, 128))
 
@@ -143,3 +144,22 @@ def test_distilbert_step_flops_match_cpu_count(one_chip):
     tpu = analyze(jax.jit(step).lower(*_on(one_chip, args)).compile()
                   .as_text()).dot_flops
     assert tpu == pytest.approx(cpu, rel=0.10)
+
+
+def test_distilbert_gathered_head_step_fits_one_chip(one_chip):
+    """The client step with the LM head at 768 gathered rows of the 4,096
+    (the capacity of 15% masks at 32 x 128) compiles for v5e, needs less
+    memory than the head at every position, and its dot FLOPs drop by the
+    head's forward and backward at the 3,328 rows it no longer runs: the
+    MLM transform (d x d) and the tied projection (d x vocab)."""
+    from repro.telemetry.cost import analyze
+    got = {}
+    for cap in (None, 768):
+        step, args = _distilbert_step("xla", head_capacity=cap)
+        compiled = jax.jit(step).lower(*_on(one_chip, args)).compile()
+        got[cap] = (analyze(compiled.as_text()).dot_flops,
+                    compiled.memory_analysis().temp_size_in_bytes)
+    d, vocab = 768, 30522
+    assert got[None][0] - got[768][0] == pytest.approx(
+        3 * 2 * (4096 - 768) * d * (d + vocab), rel=0.02)
+    assert got[768][1] < got[None][1]

@@ -18,6 +18,7 @@ _MODULES = {
     "olmoe-1b-7b": "olmoe_1b_7b",
     "llama-3.2-vision-90b": "llama_3_2_vision_90b",
     "zamba2-1.2b": "zamba2_1_2b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "phi4-mini-3.8b": "phi4_mini_3_8b",
     "distilbert-mlm": "distilbert_mlm",
 }

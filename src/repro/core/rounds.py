@@ -87,6 +87,7 @@ from repro.core.fedavg import broadcast_clients, fedavg_stacked, scalar_fold
 from repro.core.strategy import FedAvg, FederatedStrategy
 from repro.models.steps import head_capacity, make_masked_train_step
 from repro.nn import param as P
+from repro.nn.mamba import ssd_chunk
 from repro.peft.space import ParamSpace, frozen_shippable_template
 from repro.obs.metrics import registry as _obs_registry
 from repro.obs.profile import record_compile
@@ -266,6 +267,10 @@ class _ListClientData:
     def max_steps(self) -> int:
         return max(len(bs) for bs in self._batches)
 
+    @property
+    def n_built(self) -> int:
+        return len(self._batches)
+
     def batches_for(self, k: int) -> List[Dict[str, Any]]:
         return self._batches[k]
 
@@ -303,13 +308,36 @@ def _stack_shard(data, ids: Sequence[int], max_steps: int):
     """Materialize ONE shard's rectangular batch block: (shard, steps,
     B, ...) per leaf.  Short clients pad by CYCLING their local batches
     (same rule the full-width engine always used), and only this shard's
-    clients are ever resident."""
+    clients are ever resident.  Host leaves are stacked in numpy and put
+    on the device in one transfer: stacked eagerly on the device, each
+    client's batches cost a transfer and a small program per leaf, each
+    of which the device waits for between two shard programs.  Leaves a
+    provider already holds on the device are stacked there."""
     per_client = []
     for k in ids:
         bs = data.batches_for(k)
         padded = [bs[i % len(bs)] for i in range(max_steps)]
-        per_client.append(jax.tree.map(lambda *xs: jnp.stack(xs), *padded))
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *per_client)
+        per_client.append(jax.tree.map(_stack, *padded))
+    return jax.device_put(jax.tree.map(_stack, *per_client))
+
+
+def _stack(*xs):
+    if any(isinstance(x, jax.Array) for x in xs):
+        return jnp.stack(xs)
+    return np.stack(xs)
+
+
+def _make_cohort(data, part: Sequence[int]) -> int:
+    """Make the round's sampled clients' batches (a lazy provider such as
+    ``ClientPool`` builds a client on its first access) and return how many
+    were built now; counted in ``train.clients_built``.  Materialized lists
+    build none."""
+    before = data.n_built
+    for k in part:
+        data.batches_for(k)
+    built = data.n_built - before
+    _obs_registry().counter("train.clients_built").inc(built)
+    return built
 
 
 def _footprint(tree) -> Dict[str, int]:
@@ -326,19 +354,23 @@ def _head_capacity(cfg, data, part: Sequence[int]) -> Optional[int]:
     once per round before sharding so every shard runs one program.
     Records the gauges ``train.head_capacity`` (rows the head runs at) and
     ``train.head_fill`` (largest count over them), and counts a round that
-    runs the head at every position in ``train.head_full``."""
-    most = positions = 0
+    runs the head at every position in ``train.head_full``.  A model with
+    Mamba2 layers also records ``train.ssd_chunk``: the SSD chunk its shard
+    program runs at (``nn.mamba.ssd_chunk``; 0 for the token scan)."""
+    most = positions = seq = 0
     for k in part:
         for b in data.batches_for(k):
             mask = np.asarray(b["loss_mask"])
             most = max(most, int(np.count_nonzero(mask)))
-            positions = mask.size
+            positions, seq = mask.size, mask.shape[-1]
     cap = head_capacity(most, positions, cfg.mlm_mask_rate)
     rows = positions if cap is None else cap
     reg = _obs_registry()
     reg.gauge("train.head_capacity").set(rows)
     reg.gauge("train.head_fill").set(most / rows)
     reg.counter("train.head_full").inc(int(cap is None))
+    if cfg.ssm_state:
+        reg.gauge("train.ssd_chunk").set(ssd_chunk(cfg, seq))
     return cap
 
 
@@ -380,7 +412,8 @@ class FedSession:
         (re-used each round — the paper re-iterates the local dataset every
         round) — or a lazy provider (``repro.data.partition.ClientPool``)
         exposing ``batches_for(k)`` / ``sizes`` / ``max_steps`` /
-        ``__len__``, under which only sampled cohorts materialize.
+        ``n_built`` / ``__len__``, under which only sampled cohorts
+        materialize.
         ``plan.client_sizes`` defaults to per-client batch counts (n_k of
         Algorithm 1).
 
@@ -933,6 +966,9 @@ class FedSession:
                 with _obs_span("train.prepare", cat="train"):
                     part = _participants(rng, K, plan.participation)
                     m = len(part)
+                    with _obs_span("train.cohort", cat="train",
+                                   clients=m) as sp:
+                        sp.set(built=_make_cohort(data, part))
                     w = (w_all if m == K
                          else w_all[jnp.asarray(part, jnp.int32)])
                     w_agg, w_loss = norm_weights(w)

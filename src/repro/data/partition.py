@@ -112,7 +112,7 @@ class ClientPool:
     """Virtual population of ``n_clients`` federated clients backed by a
     small pool of real data shards — the lazy client-data provider the
     round engines consume (``batches_for`` / ``sizes`` / ``max_steps`` /
-    ``__len__``).
+    ``n_built`` / ``__len__``).
 
     Cross-device populations are sampled, not enumerated: a 100k–1M-client
     round touches only its cohort, so materializing every client's batches
@@ -162,6 +162,11 @@ class ClientPool:
     def materialized(self) -> List[int]:
         """Pool shard indices built so far (laziness observability)."""
         return sorted(self._cache)
+
+    @property
+    def n_built(self) -> int:
+        """How many pool shards have been built so far."""
+        return len(self._cache)
 
     def _shard(self, i: int):
         if i not in self._cache:

@@ -50,7 +50,7 @@ class Knobs:
     opt_state_dtype: Optional[str] = None     # None -> param dtype
     remat: Optional[bool] = None              # None -> config default
     opt_rules: bool = False                   # OPT_RULES (context-parallel attn)
-    impl: str = "xla"                         # "chunked": blockwise SSM scans
+    impl: str = "xla"                         # "chunked": blockwise RWKV scans
     frozen_frac: float = 0.0                  # FFDAPT window fraction (train)
     moe_groups: int = 0                       # local (per-group) MoE dispatch
 

@@ -14,8 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.nn import param as P
-from repro.nn.attention import apply_attention, init_attention
-from repro.nn.layers import apply_mlp, apply_norm, init_mlp, init_norm
+from repro.nn.attention import _gqa_scores_combine, apply_attention, init_attention
+from repro.nn.layers import apply_mlp, apply_norm, init_mlp, init_norm, project
 from repro.nn.mamba import apply_mamba2, init_mamba2
 from repro.nn.moe import apply_moe, init_moe
 from repro.nn.param import ParamCtx
@@ -60,6 +60,7 @@ def init_transformer_block(ctx: ParamCtx, cfg, *, cross: bool = False):
 PEFT_TARGETS = {
     "attn": {"wq": (1, 2), "wk": (1, 2), "wv": (1, 2), "wo": (2, 1)},
     "mlp": {"wi_gate": (1, 1), "wi_up": (1, 1), "wi": (1, 1), "wo": (1, 1)},
+    "mamba": {"in_proj": (1, 1), "out_proj": (1, 1)},
 }
 
 # Path components under which each target group's leaves live.  "attn" covers
@@ -67,6 +68,7 @@ PEFT_TARGETS = {
 PEFT_GROUPS = {
     "attn": ("attn", "xattn"),
     "mlp": ("mlp",),
+    "mamba": ("mamba",),
 }
 
 
@@ -125,23 +127,18 @@ def apply_cross_block(p, x, cfg, lc, *, mode, kv_embeds=None, positions=None,
         h = apply_norm(p["lnx"], x, cfg.norm_type, cfg.norm_eps)
         if mode == "decode" and lc and "xk" in lc:
             # reuse projected image kv from the cache
-            from repro.nn.attention import _gqa_scores_combine
             dt = x.dtype
-            q = jnp.einsum("...d,dhk->...hk", h, p["xattn"]["wq"].astype(dt))
+            q = project("...d,dhk->...hk", h, p["xattn"]["wq"])
             mask = jnp.zeros((1, 1, 1, lc["xk"].shape[1]), jnp.float32)
             out = _gqa_scores_combine(q, lc["xk"].astype(dt),
                                       lc["xv"].astype(dt), mask)
-            a = jnp.einsum("...hk,hkd->...d", out,
-                           p["xattn"]["wo"].astype(dt))
+            a = project("...hk,hkd->...d", out, p["xattn"]["wo"])
             nxk, nxv = lc["xk"], lc["xv"]
         else:
             a, _, _ = apply_attention(p["xattn"], h, cfg, mode="train",
                                       causal=False, kv_x=kv_embeds, impl=impl)
-            dt = x.dtype
-            nxk = jnp.einsum("...d,dhk->...hk", kv_embeds,
-                             p["xattn"]["wk"].astype(dt))
-            nxv = jnp.einsum("...d,dhk->...hk", kv_embeds,
-                             p["xattn"]["wv"].astype(dt))
+            nxk = project("...d,dhk->...hk", kv_embeds, p["xattn"]["wk"])
+            nxv = project("...d,dhk->...hk", kv_embeds, p["xattn"]["wv"])
         x = x + gate_a * a
     with jax.named_scope("mlp"):
         h = apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps)
@@ -185,25 +182,21 @@ def apply_encdec_block(p, x, cfg, lc, *, mode, enc_out=None, positions=None,
     with jax.named_scope("attn"):
         h = apply_norm(p["lnx"], x, cfg.norm_type, cfg.norm_eps)
         if mode == "decode" and lc and "xk" in lc:
-            from repro.nn.attention import _gqa_scores_combine
             dt = x.dtype
-            q = jnp.einsum("...d,dhk->...hk", h, p["xattn"]["wq"].astype(dt))
+            q = project("...d,dhk->...hk", h, p["xattn"]["wq"])
             if "bq" in p["xattn"]:
                 q = q + p["xattn"]["bq"].astype(dt)
             mask = jnp.zeros((1, 1, 1, lc["xk"].shape[1]), jnp.float32)
             out = _gqa_scores_combine(q, lc["xk"].astype(dt),
                                       lc["xv"].astype(dt), mask)
-            a = jnp.einsum("...hk,hkd->...d", out,
-                           p["xattn"]["wo"].astype(dt))
+            a = project("...hk,hkd->...d", out, p["xattn"]["wo"])
             nxk, nxv = lc["xk"], lc["xv"]
         else:
             a, _, _ = apply_attention(p["xattn"], h, cfg, mode="train",
                                       causal=False, kv_x=enc_out, impl=impl)
             dt = x.dtype
-            nxk = jnp.einsum("...d,dhk->...hk", enc_out,
-                             p["xattn"]["wk"].astype(dt))
-            nxv = jnp.einsum("...d,dhk->...hk", enc_out,
-                             p["xattn"]["wv"].astype(dt))
+            nxk = project("...d,dhk->...hk", enc_out, p["xattn"]["wk"])
+            nxv = project("...d,dhk->...hk", enc_out, p["xattn"]["wv"])
             if "bk" in p["xattn"]:
                 nxk = nxk + p["xattn"]["bk"].astype(dt)
                 nxv = nxv + p["xattn"]["bv"].astype(dt)
@@ -272,9 +265,61 @@ def apply_mamba_block(p, x, cfg, lc, *, impl="xla"):
     """lc: {conv (B,W-1,CC), ssm (B,H,P,N)} or None."""
     conv = lc["conv"] if lc else None
     ssm = lc["ssm"] if lc else None
-    h = apply_norm(p["ln"], x, cfg.norm_type, cfg.norm_eps)
-    y, nconv, nssm = apply_mamba2(p["mamba"], h, cfg, conv_state=conv,
-                                  ssm_state=ssm, impl=impl)
-    x = x + y
+    with jax.named_scope("mamba"):
+        h = apply_norm(p["ln"], x, cfg.norm_type, cfg.norm_eps)
+        y, nconv, nssm = apply_mamba2(p["mamba"], h, cfg, conv_state=conv,
+                                      ssm_state=ssm, impl=impl)
+        x = x + y
     nlc = {"conv": nconv, "ssm": nssm} if lc is not None else None
     return x, nlc, jnp.zeros((), jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Hybrid layer (granite-4.0-h): [Mamba2 or attention mixer -> MLP]
+# ---------------------------------------------------------------------------
+
+def init_hybrid_layer(ctx: ParamCtx, cfg, kind: str):
+    """One layer of a ``hybrid_mlp`` stack; ``kind`` is "mamba" or
+    "attention"."""
+    p = {"ln1": init_norm(ctx.sub("ln1"), cfg.d_model, cfg.norm_type),
+         "ln2": init_norm(ctx.sub("ln2"), cfg.d_model, cfg.norm_type),
+         "mlp": init_mlp(ctx.sub("mlp"), cfg.d_model, cfg.d_ff, cfg.mlp_type)}
+    if kind == "mamba":
+        p["mamba"] = init_mamba2(ctx.sub("mamba"), cfg.d_model, cfg.ssm_state,
+                                 expand=cfg.ssm_expand, conv_dim=cfg.conv_dim)
+    else:
+        p["attn"] = init_attention(ctx.sub("attn"), cfg.d_model, cfg.n_heads,
+                                   cfg.n_kv_heads, cfg.head_dim_,
+                                   qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm)
+    return p
+
+
+def apply_hybrid_layer(p, x, cfg, lc, kind: str, *, mode, positions=None,
+                       cache_index=None, impl="xla"):
+    """Pre-norm mixer then pre-norm MLP, each residual branch scaled by
+    ``cfg.residual_multiplier``.  lc: {conv, ssm} (mamba) or {k, v}
+    (attention), or None.  Named scopes ``mamba`` or ``attn``, then
+    ``mlp``."""
+    r = cfg.residual_multiplier
+    if kind == "mamba":
+        with jax.named_scope("mamba"):
+            h = apply_norm(p["ln1"], x, cfg.norm_type, cfg.norm_eps)
+            y, nconv, nssm = apply_mamba2(
+                p["mamba"], h, cfg, conv_state=lc["conv"] if lc else None,
+                ssm_state=lc["ssm"] if lc else None, impl=impl)
+            x = x + r * y
+        nlc = {"conv": nconv, "ssm": nssm} if lc is not None else None
+    else:
+        with jax.named_scope("attn"):
+            h = apply_norm(p["ln1"], x, cfg.norm_type, cfg.norm_eps)
+            a, nk, nv = apply_attention(
+                p["attn"], h, cfg, mode=mode, causal=True,
+                cache_k=lc["k"] if lc else None,
+                cache_v=lc["v"] if lc else None, cache_index=cache_index,
+                positions=positions, impl=impl)
+            x = x + r * a
+        nlc = {"k": nk, "v": nv} if lc is not None else None
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps)
+        x = x + r * apply_mlp(p["mlp"], h, cfg.mlp_type)
+    return x, nlc

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    arch_type: str                     # dense | moe | ssm | hybrid | vlm | audio | mlm
+    arch_type: str                     # dense | moe | ssm | hybrid | hybrid_mlp | vlm | audio | mlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -30,6 +30,7 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10000.0
     use_rope: bool = True              # False -> learned absolute positions (BERT/Whisper)
+    nope: bool = False                 # no positions at all (Granite-4.0-H): no RoPE, no table
     sliding_window: int = 0            # 0 = full attention; >0 = windowed (ring cache)
     attn_logit_softcap: float = 0.0
 
@@ -55,6 +56,19 @@ class ModelConfig:
 
     # hybrid (zamba2): shared attention block applied at these (0-based) depths
     shared_attn_positions: Tuple[int, ...] = ()
+
+    # hybrid_mlp (granite-4.0-h): every layer is [mixer -> MLP]; the mixer
+    # kinds of one period in published order ("mamba" | "attention"),
+    # repeated n_layers / len(layer_pattern) times
+    layer_pattern: Tuple[str, ...] = ()
+
+    # Granite's multipliers: embeddings x embedding_multiplier, attention
+    # scores x attention_multiplier (0 -> 1/sqrt(head_dim)), each residual
+    # branch x residual_multiplier, logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # VLM (llama-3.2-vision): a cross-attention layer every N layers
     cross_attn_every: int = 0
@@ -92,7 +106,8 @@ class ModelConfig:
 
     @property
     def is_decoder_only(self) -> bool:
-        return self.arch_type in ("dense", "moe", "ssm", "hybrid", "vlm")
+        return self.arch_type in ("dense", "moe", "ssm", "hybrid", "hybrid_mlp",
+                                  "vlm")
 
     @property
     def subquadratic(self) -> bool:
@@ -107,7 +122,8 @@ class ModelConfig:
         return self.replace(sliding_window=window)
 
     def reduced(self) -> "ModelConfig":
-        """CPU-smoke variant of the same family: 2 layers, d_model<=512, <=4 experts."""
+        """CPU-smoke variant of the same family: 2 layers (one whole period
+        of a ``layer_pattern``), d_model<=512, <=4 experts."""
         d_model = min(self.d_model, 256)
         n_heads = min(self.n_heads, 4)
         n_kv = max(1, min(self.n_kv_heads, n_heads))
@@ -115,7 +131,7 @@ class ModelConfig:
         if self.n_kv_heads < self.n_heads:
             n_kv = max(1, n_heads // 2)
         kw = dict(
-            n_layers=2,
+            n_layers=len(self.layer_pattern) or 2,
             d_model=d_model,
             n_heads=n_heads,
             n_kv_heads=n_kv,
@@ -151,5 +167,11 @@ class ModelConfig:
             assert 0 < self.top_k <= self.n_experts
         if self.arch_type == "vlm":
             assert self.cross_attn_every > 0 and self.n_image_tokens > 0
+        if self.arch_type == "hybrid_mlp":
+            kinds = set(self.layer_pattern)
+            assert kinds and kinds <= {"mamba", "attention"}, self.layer_pattern
+            assert self.n_layers % len(self.layer_pattern) == 0, \
+                f"{self.name}: {self.n_layers} layers, period {len(self.layer_pattern)}"
+            assert self.ssm_state > 0
         if self.arch_type == "audio":
             assert self.encoder_layers > 0 and self.n_audio_frames > 0

@@ -14,12 +14,15 @@ Freeze units (what Algorithm 1's N counts) per family:
   uniform stacks (dense/moe/mlm/ssm): one unit per layer.
   hybrid:  one unit per mamba block (the shared attention block is shared
            across positions and stays trainable — see DESIGN §Arch-applicability).
+  hybrid_mlp: one unit per layer; frozen windows are not supported (the
+           stack scans over whole periods of its layer pattern).
   vlm:     one unit per (cross_attn_every-1 self + 1 cross) group.
   audio:   encoder layers ++ decoder layers, concatenated.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -68,7 +71,7 @@ def init_model(key: jax.Array, cfg) -> Any:
     ctx = ParamCtx(key, cfg.pdtype)
     p: Dict[str, Any] = {"embed": init_embedding(ctx.sub("embed"),
                                                  cfg.vocab_size, cfg.d_model)}
-    if not cfg.use_rope and cfg.arch_type != "ssm":
+    if not cfg.use_rope and not cfg.nope and cfg.arch_type != "ssm":
         p["pos"] = init_positional(ctx.sub("pos"), cfg.max_seq_len, cfg.d_model)
 
     at = cfg.arch_type
@@ -83,6 +86,13 @@ def init_model(key: jax.Array, cfg) -> Any:
         p["layers"] = init_stack(ctx, "layers", cfg.n_layers,
                                  lambda c: B.init_mamba_block(c, cfg))
         p["shared_attn"] = B.init_transformer_block(ctx.sub("shared_attn"), cfg)
+    elif at == "hybrid_mlp":
+        def init_period(c):
+            return {kind: init_stack(c, kind, n, functools.partial(
+                        B.init_hybrid_layer, cfg=cfg, kind=kind))
+                    for kind, n in _pattern_counts(cfg).items()}
+
+        p["layers"] = init_stack(ctx, "layers", _n_periods(cfg), init_period)
     elif at == "vlm":
         per = cfg.cross_attn_every - 1
         G = cfg.n_layers // cfg.cross_attn_every
@@ -118,6 +128,19 @@ def init_model(key: jax.Array, cfg) -> Any:
             "ln": init_norm(ctx.sub("mlm_ln"), cfg.d_model, cfg.norm_type),
         }
     return p
+
+
+def _pattern_counts(cfg) -> Dict[str, int]:
+    """hybrid_mlp: layers of each mixer kind in one period, in order of
+    first appearance."""
+    out: Dict[str, int] = {}
+    for kind in cfg.layer_pattern:
+        out[kind] = out.get(kind, 0) + 1
+    return out
+
+
+def _n_periods(cfg) -> int:
+    return cfg.n_layers // len(cfg.layer_pattern)
 
 
 # ---------------------------------------------------------------------------
@@ -162,6 +185,25 @@ def cache_struct(cfg, batch: int, cache_len: int, dtype=None) -> Any:
         }
         c["shared"] = {"k": _box((A, batch, cache_len, Kv, D), dt, kvax),
                        "v": _box((A, batch, cache_len, Kv, D), dt, kvax)}
+    elif at == "hybrid_mlp":
+        from repro.nn.mamba import HEAD_P
+        _, H, CC = mamba_dims(cfg.d_model, cfg.ssm_state, cfg.ssm_expand,
+                              cfg.conv_dim)
+        n = _n_periods(cfg)
+        two = (P.LAYERS, P.LAYERS)
+        c["layers"] = {}
+        for kind, m in _pattern_counts(cfg).items():
+            if kind == "mamba":
+                c["layers"][kind] = {
+                    "conv": _box((n, m, batch, cfg.conv_dim - 1, CC), dt,
+                                 two + (P.BATCH, None, P.FFN)),
+                    "ssm": _box((n, m, batch, H, HEAD_P, cfg.ssm_state),
+                                jnp.float32,
+                                two + (P.BATCH, P.HEADS, None, P.DSTATE))}
+            else:
+                c["layers"][kind] = {
+                    "k": _box((n, m, batch, C, Kv, D), dt, two + kvax[1:]),
+                    "v": _box((n, m, batch, C, Kv, D), dt, two + kvax[1:])}
     elif at == "vlm":
         per = cfg.cross_attn_every - 1
         G = cfg.n_layers // cfg.cross_attn_every
@@ -209,9 +251,10 @@ def _learned_pos(p, positions, max_len, dtype):
 
 def _head(params, cfg, x, rows=None):
     """Final norm, the MLM transform and the vocabulary projection (named
-    scope ``lm_head`` on the device trace).  ``rows`` (flat position
-    indices over batch x sequence) gathers those positions first, so the
-    head and its backward run at them alone; logits are then (rows, V)."""
+    scope ``lm_head`` on the device trace), logits divided by
+    ``cfg.logits_scaling``.  ``rows`` (flat position indices over batch x
+    sequence) gathers those positions first, so the head and its backward
+    run at them alone; logits are then (rows, V)."""
     with jax.named_scope("lm_head"):
         if rows is not None:
             x = x.reshape(-1, x.shape[-1])[rows]
@@ -223,9 +266,13 @@ def _head(params, cfg, x, rows=None):
             x = jax.nn.gelu(x)
             x = apply_norm(t["ln"], x, cfg.norm_type, cfg.norm_eps)
         if cfg.tie_embeddings:
-            return apply_lm_head(None, x,
-                                 embedding_table=params["embed"]["table"])
-        return apply_lm_head(params["lm_head"], x)
+            logits = apply_lm_head(None, x,
+                                   embedding_table=params["embed"]["table"])
+        else:
+            logits = apply_lm_head(params["lm_head"], x)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
 
 def apply_model(params, cfg, batch: Dict[str, Any], *, mode: str = "train",
@@ -250,6 +297,8 @@ def apply_model(params, cfg, batch: Dict[str, Any], *, mode: str = "train",
 
     with jax.named_scope("embed"):
         x = apply_embedding(params["embed"], tokens, dt)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         x = constrain(x, (P.BATCH, P.SEQ, P.EMBED))
         if "pos" in params and cfg.arch_type != "audio":
             x = x + _learned_pos(params["pos"], positions, cfg.max_seq_len,
@@ -293,6 +342,11 @@ def apply_model(params, cfg, batch: Dict[str, Any], *, mode: str = "train",
 
     elif at == "hybrid":
         x, new_layers, new_shared, aux_total = _apply_hybrid(
+            params, cfg, x, cache, mode=mode, positions=positions,
+            index=index, frozen=frozen, impl=impl)
+
+    elif at == "hybrid_mlp":
+        x, new_layers = _apply_hybrid_mlp(
             params, cfg, x, cache, mode=mode, positions=positions,
             index=index, frozen=frozen, impl=impl)
 
@@ -382,6 +436,51 @@ def _apply_hybrid(params, cfg, x, cache, *, mode, positions, index, frozen, impl
     if shared is not None:
         new_shared = {"k": jnp.stack(new_shared_k), "v": jnp.stack(new_shared_v)}
     return x, new_layers, new_shared, sum(auxs)
+
+
+# ---------------------------------------------------------------------------
+# Hybrid with an MLP in every layer (granite-4.0-h): a scan over periods
+# ---------------------------------------------------------------------------
+
+def _apply_hybrid_mlp(params, cfg, x, cache, *, mode, positions, index,
+                      frozen, impl):
+    """Scan over whole periods of ``cfg.layer_pattern``; the parameters (and
+    caches) are stacked per mixer kind as (periods, layers of that kind in
+    a period, ...), and each period runs its layers in published order,
+    each under its own remat."""
+    if frozen is not None and any(frozen):
+        raise NotImplementedError(
+            f"{cfg.name}: FFDAPT frozen windows over a hybrid_mlp stack")
+
+    def period(x, xs):
+        pp, pc = xs
+        taken = {kind: 0 for kind in pp}
+        new = {kind: [] for kind in pp}
+        for kind in cfg.layer_pattern:
+            i = taken[kind]
+            taken[kind] += 1
+            lp = jax.tree.map(lambda t: t[i], pp[kind])
+            lc = (jax.tree.map(lambda t: t[i], pc[kind]) if pc is not None
+                  else None)
+
+            def layer(lp, x, lc, kind=kind):
+                x = constrain(x, (P.BATCH, P.SEQ, P.EMBED))
+                return B.apply_hybrid_layer(lp, x, cfg, lc, kind, mode=mode,
+                                            positions=positions,
+                                            cache_index=index, impl=impl)
+
+            x, nlc = (jax.checkpoint(layer) if cfg.remat else layer)(lp, x,
+                                                                     lc)
+            new[kind].append(nlc)
+        if pc is None:
+            return x, None
+        return x, {kind: jax.tree.map(lambda *ts: jnp.stack(ts), *outs)
+                   for kind, outs in new.items()}
+
+    lcs = cache["layers"] if cache is not None else None
+    n = _n_periods(cfg)
+    return jax.lax.scan(period, x, (P.unbox_if(params["layers"]), lcs),
+                        unroll=n if cfg.scan_unroll else 1)
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.nn import param as P
-from repro.nn.layers import apply_rope, apply_rmsnorm
+from repro.nn.layers import apply_rope, apply_rmsnorm, project
 from repro.nn.param import ParamCtx
 from repro.sharding.ctx import constrain
 
@@ -86,9 +86,9 @@ def init_attention(ctx: ParamCtx, d_model: int, n_heads: int, n_kv: int,
 
 def _project_qkv(params, x, kv_x, *, qk_norm, norm_eps):
     dt = x.dtype
-    q = jnp.einsum("...d,dhk->...hk", x, params["wq"].astype(dt))
-    k = jnp.einsum("...d,dhk->...hk", kv_x, params["wk"].astype(dt))
-    v = jnp.einsum("...d,dhk->...hk", kv_x, params["wv"].astype(dt))
+    q = project("...d,dhk->...hk", x, params["wq"])
+    k = project("...d,dhk->...hk", kv_x, params["wk"])
+    v = project("...d,dhk->...hk", kv_x, params["wv"])
     if "bq" in params:
         q = q + params["bq"].astype(dt)
         k = k + params["bk"].astype(dt)
@@ -99,14 +99,19 @@ def _project_qkv(params, x, kv_x, *, qk_norm, norm_eps):
     return q, k, v
 
 
-def _gqa_scores_combine(q, k, v, mask, *, softcap=0.0):
-    """q: (B,S,H,D); k/v: (B,T,Kv,D); mask: broadcastable (B,1,S,T) additive."""
+def _gqa_scores_combine(q, k, v, mask, *, softcap=0.0, scale=0.0):
+    """q: (B,S,H,D); k/v: (B,T,Kv,D); mask: broadcastable (B,1,S,T) additive.
+    ``scale`` multiplies the scores (Granite's attention multiplier); 0
+    divides them by sqrt(D)."""
     B, S, H, D = q.shape
     Kv = k.shape[2]
     G = H // Kv
     qg = q.reshape(B, S, Kv, G, D)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(D).astype(jnp.float32)
+    if scale:
+        scores = scores * jnp.float32(scale)
+    else:
+        scores = scores / jnp.sqrt(D).astype(jnp.float32)
     if softcap:
         scores = softcap * jnp.tanh(scores / softcap)
     scores = scores + mask[:, :, None, :, :] if mask.ndim == 4 else scores + mask
@@ -152,11 +157,12 @@ def apply_attention(params, x, cfg, *, mode="train", causal=True,
     if positions is None:
         positions = jnp.arange(S)[None, :]
 
-    if cfg.use_rope and not cross:
+    if cfg.use_rope and not cfg.nope and not cross:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
     window = cfg.sliding_window
+    scale = cfg.attention_multiplier
     if mode == "train" or (mode == "prefill" and cache_k is None):
         if cross:
             mask = jnp.zeros((S, k.shape[1]), jnp.float32)
@@ -166,10 +172,13 @@ def apply_attention(params, x, cfg, *, mode="train", causal=True,
             mask = jnp.zeros((S, S), jnp.float32)
         if impl == "pallas" and not cross:
             from repro.kernels import ops as kops
+            if scale:       # the kernel divides by sqrt(D)
+                q = q * jnp.asarray(scale * hd ** 0.5, q.dtype)
             out = kops.flash_attention(q, k, v, causal=causal, window=window,
                                        softcap=cfg.attn_logit_softcap)
         else:
-            out = _gqa_scores_combine(q, k, v, mask, softcap=cfg.attn_logit_softcap)
+            out = _gqa_scores_combine(q, k, v, mask, softcap=cfg.attn_logit_softcap,
+                                      scale=scale)
         new_k, new_v = cache_k, cache_v
 
     elif mode == "prefill":
@@ -184,7 +193,8 @@ def apply_attention(params, x, cfg, *, mode="train", causal=True,
             new_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k, 0, axis=1)
             new_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v, 0, axis=1)
         mask = _causal_mask(S, S, window=window)
-        out = _gqa_scores_combine(q, k, v, mask, softcap=cfg.attn_logit_softcap)
+        out = _gqa_scores_combine(q, k, v, mask, softcap=cfg.attn_logit_softcap,
+                                  scale=scale)
 
     elif mode == "decode":
         C = cache_k.shape[1]
@@ -203,12 +213,11 @@ def apply_attention(params, x, cfg, *, mode="train", causal=True,
         mask = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)  # (C,)
         mask = mask[None, None, None, :]                    # (1,1,1,C): bcast B, heads, S
         out = _gqa_scores_combine(q, new_k, new_v, mask,
-                                  softcap=cfg.attn_logit_softcap)
+                                  softcap=cfg.attn_logit_softcap, scale=scale)
     else:
         raise ValueError(mode)
 
-    dt = x.dtype
     out = constrain(out, (P.BATCH, P.ATTN_SEQ, P.HEADS, P.HEAD_DIM))
-    out = jnp.einsum("...hk,hkd->...d", out, params["wo"].astype(dt))
+    out = project("...hk,hkd->...d", out, params["wo"])
     out = constrain(out, (P.BATCH, P.SEQ, P.EMBED))
     return out, new_k, new_v

@@ -7,7 +7,8 @@ shape-polymorphic over leading batch/seq dims where possible.
 
 from __future__ import annotations
 
-from typing import Optional
+import dataclasses
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,65 @@ import jax.numpy as jnp
 from repro.nn import param as P
 from repro.nn.param import Box, ParamCtx
 from repro.sharding.ctx import constrain
+
+
+# ---------------------------------------------------------------------------
+# Projections, with a low-rank bank applied unmerged
+# ---------------------------------------------------------------------------
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class LowRank:
+    """A frozen projection ``w`` with its trainable low-rank factors, as
+    ``repro.peft.ParamSpace.unmerged`` puts them in place of the weight.
+    ``w`` is (stack..., in dims..., out dims...) with ``n_in`` input and
+    ``n_out`` output dims; ``a`` is (stack..., d_in | d_out, r) and ``b``
+    (stack..., r, d_out), so slicing a layer stack slices all three.
+    ``kind`` "lora" adds ``scale * (x A) B``; "adapter" adds
+    ``((x W) D) U``.  The factors never meet ``w``: no merged weight is
+    formed, so a delta far below ``w``'s rounding still reaches the
+    output and the gradient reaches only ``a`` and ``b``."""
+
+    w: Any
+    a: Any
+    b: Any
+    scale: float = 1.0
+    kind: str = "lora"
+    n_in: int = 1
+    n_out: int = 1
+
+    def tree_flatten(self):
+        return (self.w, self.a, self.b), (self.scale, self.kind, self.n_in,
+                                          self.n_out)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+
+def project(spec: str, x, w):
+    """``einsum(spec, x, w)`` in ``x``'s dtype, the one call every adaptable
+    projection makes.  A :class:`LowRank` ``w`` adds its bank's term,
+    computed in float32 (named scope ``lora``) and added to the frozen
+    projection before the one rounding to ``x``'s dtype."""
+    if not isinstance(w, LowRank):
+        return jnp.einsum(spec, x, w.astype(x.dtype))
+    # the frozen projection stays in float32 until the bank's term is in:
+    # rounded first, it would sit on x's grid, and a term under half its
+    # spacing would round away entirely
+    y = jnp.einsum(spec, x, w.w.astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    lead = y.shape[:y.ndim - w.n_out]
+    with jax.named_scope("lora"):
+        if w.kind == "adapter":
+            src = y.reshape(lead + (-1,))
+            low = (src @ w.a.astype(jnp.float32)) @ w.b.astype(jnp.float32)
+        else:
+            src = x.reshape(x.shape[:x.ndim - w.n_in] + (-1,))
+            low = ((src.astype(jnp.float32) @ w.a.astype(jnp.float32))
+                   @ w.b.astype(jnp.float32)) * w.scale
+        out = y + low.reshape(y.shape)
+    return out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -104,17 +164,17 @@ def _ffn_axes(h):
 
 def apply_mlp(params, x, kind: str):
     if kind == "swiglu":
-        g = jnp.einsum("...d,df->...f", x, params["wi_gate"].astype(x.dtype))
-        u = jnp.einsum("...d,df->...f", x, params["wi_up"].astype(x.dtype))
+        g = project("...d,df->...f", x, params["wi_gate"])
+        u = project("...d,df->...f", x, params["wi_up"])
         h = constrain(jax.nn.silu(g) * u, _ffn_axes(g))
-        return jnp.einsum("...f,fd->...d", h, params["wo"].astype(x.dtype))
-    h = jnp.einsum("...d,df->...f", x, params["wi"].astype(x.dtype)) + params["bi"].astype(x.dtype)
+        return project("...f,fd->...d", h, params["wo"])
+    h = project("...d,df->...f", x, params["wi"]) + params["bi"].astype(x.dtype)
     h = constrain(h, _ffn_axes(h))
     if kind == "gelu":
         h = jax.nn.gelu(h)
     elif kind == "relu2":                       # Nemotron-4 squared ReLU
         h = jnp.square(jax.nn.relu(h))
-    return jnp.einsum("...f,fd->...d", h, params["wo"].astype(x.dtype)) + params["bo"].astype(x.dtype)
+    return project("...f,fd->...d", h, params["wo"]) + params["bo"].astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

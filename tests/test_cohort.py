@@ -16,7 +16,7 @@ from repro.configs import get_config
 from repro.core.ffdapt import FFDAPTConfig
 from repro.core.noniid import make_client_datasets, make_client_pool
 from repro.core.rounds import (FedSession, RoundPlan, _participants,
-                               _shard_widths)
+                               _shard_widths, _stack_shard)
 from repro.core.strategies import AsyncFedAvg
 from repro.core.strategy import Compressed, FedAvg, FedAvgM, FedProx
 from repro.data.corpus import generate_corpus
@@ -221,6 +221,42 @@ def test_resume_across_different_shard_size(params0, clients, tmp_path):
     _assert_bitwise(p_full, p_b)
     assert [h.clients for h in h_b] == [h.clients for h in h_full]
     assert [h.loss for h in h_b] == [h.loss for h in h_full]
+
+
+# ------------------------------------------------------- shard stacking
+
+def test_stack_shard_keeps_device_leaves_on_the_device(monkeypatch):
+    """A shard's block pads short clients by cycling their batches; host
+    leaves are stacked in numpy, and a leaf the provider holds on the
+    device never goes through numpy (which would copy it to the host)."""
+    class Provider:
+        def __init__(self, on_device):
+            self.on_device = on_device
+
+        def batches_for(self, k):
+            return [{"tokens": (jnp.full if self.on_device else np.full)(
+                         (2, 3), 10 * k + i, np.int32),
+                     "loss_mask": np.full((2, 3), i % 2, np.float32)}
+                    for i in range(k + 1)]
+
+    real, seen = np.stack, []
+
+    def spy(xs, *a, **kw):
+        seen.append(any(isinstance(x, jax.Array) for x in xs))
+        return real(xs, *a, **kw)
+
+    monkeypatch.setattr(np, "stack", spy)
+    host = _stack_shard(Provider(False), [0, 2], 3)
+    assert seen and not any(seen)
+    seen.clear()
+    mixed = _stack_shard(Provider(True), [0, 2], 3)
+    assert seen and not any(seen)
+    for got in (host, mixed):
+        assert all(isinstance(v, jax.Array) for v in got.values())
+        np.testing.assert_array_equal(got["tokens"][:, :, 0, 0],
+                                      [[0, 0, 0], [20, 21, 22]])
+        np.testing.assert_array_equal(got["loss_mask"][:, :, 0, 0],
+                                      [[0, 0, 0], [0, 1, 0]])
 
 
 # ------------------------------------------------------- Floyd sampling

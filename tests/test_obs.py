@@ -561,12 +561,59 @@ def test_parallel_engine_span_tree(round_programs):
            if e.name.startswith("train.")}
     assert got == {"train.round": (None, 0),
                    "train.prepare": ("train.round", 0),
+                   "train.cohort": ("train.prepare", 0),
                    "train.dispatch": ("train.round", 0),
                    "train.stack": ("train.dispatch", 0),
                    "train.launch": ("train.dispatch", 0),
                    "train.combine": ("train.round", 0),
                    "train.wait": ("train.round", 0),
                    "train.account": (None, 0)}
+
+
+def test_cohort_span_counts_the_clients_it_builds():
+    """Under a lazy ``ClientPool`` the cohort-scan engine makes each
+    round's sampled clients in ``train.cohort`` (inside ``train.prepare``),
+    whose args say how many it sampled and built; ``train.clients_built``
+    counts every build, and a client sampled again is not built again."""
+    from repro import optim
+    from repro.configs import get_config
+    from repro.core.noniid import make_client_datasets
+    from repro.core.rounds import FedSession, RoundPlan
+    from repro.data.corpus import generate_corpus
+    from repro.data.partition import ClientPool
+    from repro.models.model import init_model
+    from repro.nn import param as P
+
+    cfg = get_config("distilbert-mlm").reduced()
+    params0 = P.unbox(init_model(jax.random.PRNGKey(0), cfg))
+    ds = make_client_datasets(generate_corpus(30, seed=2), cfg, k=6,
+                              skew="iid", batch=2, seq=16)
+    shards = [b[:1] for b in ds["batches"]]
+
+    class Lazy(ClientPool):
+        max_steps = 1          # known, so nothing is built to learn it
+
+    pool = Lazy(6, [lambda i=i: shards[i] for i in range(6)], [1] * 6)
+    tr = obs.enable()
+    obs.registry().clear()
+    try:
+        _, hist = FedSession(cfg, optim.adam(1e-3), RoundPlan(
+            n_rounds=3, engine="parallel", participation=0.5,
+            telemetry=False)).run(params0, pool)
+        events = tr.events()
+        built = obs.registry().snapshot()["train.clients_built"]["value"]
+    finally:
+        obs.disable()
+    spans = [e for e in events if e.name == "train.cohort"]
+    assert [(e.parent, e.round) for e in spans] == [("train.prepare", t)
+                                                    for t in range(3)]
+    assert all(e.args["clients"] == 3 for e in spans)
+    seen, want = set(), []
+    for rr in hist:
+        want.append(len(set(rr.clients) - seen))
+        seen |= set(rr.clients)
+    assert [e.args["built"] for e in spans] == want
+    assert built == sum(want) == pool.n_built == len(seen)
 
 
 def test_session_drift_ratios_within_tolerance(traced_session):
